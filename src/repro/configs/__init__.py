@@ -30,11 +30,18 @@ ARCH_IDS: List[str] = list(_MODULES)
 
 
 def get_config(arch_id: str, **overrides) -> ModelConfig:
+    """The published config of ``arch_id`` with ``overrides`` applied.
+
+    ``n_layers=k`` is a depth cut (:meth:`ModelConfig.with_depth`): the
+    first k layers, whole periods of the published layer pattern, with
+    every width untouched."""
     key = arch_id.lower()
     if key not in _MODULES:
         raise KeyError(f"unknown arch {arch_id!r}; available: {ARCH_IDS}")
     mod = importlib.import_module(f"repro.configs.{_MODULES[key]}")
     cfg: ModelConfig = mod.CONFIG
+    if "n_layers" in overrides:
+        cfg = cfg.with_depth(overrides.pop("n_layers"))
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     return cfg

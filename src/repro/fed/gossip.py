@@ -32,22 +32,6 @@ from jax.sharding import PartitionSpec as P
 from repro.core.birkhoff import birkhoff_decomposition
 from repro.obs import metrics as obs_metrics
 
-if hasattr(jax, "shard_map"):  # jax >= 0.6
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-
-else:  # jax 0.4.x: experimental API, check_rep instead of check_vma
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(fn, mesh, in_specs, out_specs):
-        return _exp_shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
-
-
 @dataclass(frozen=True)
 class GossipPlan:
     """Compiled consensus schedule: one overlay's mixing as collectives.
@@ -373,7 +357,8 @@ def gossip_shard_map(
     leaves, treedef = jax.tree_util.tree_flatten(params)
     specs = [P(axis, *([None] * (l.ndim - 1))) for l in leaves]
     in_spec = jax.tree_util.tree_unflatten(treedef, specs)
-    fn = _shard_map(mix_tree, mesh, (in_spec,), in_spec)
+    fn = jax.shard_map(mix_tree, mesh=mesh, in_specs=(in_spec,),
+                       out_specs=in_spec, check_vma=False)
     return fn(params)
 
 
@@ -390,7 +375,7 @@ def _pallas_mix_tree(
     ident = tuple(range(plan.n_silos))
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     shapes = [l.shape for l in leaves]
-    sizes = [int(np.prod(s)) for s in shapes]
+    sizes = [l.size for l in leaves]
     flat = jnp.concatenate([l.reshape(-1) for l in leaves])
     stack = []
     weights = []

@@ -2,22 +2,18 @@
 
 ``interpret=None`` everywhere means *auto*: lower via Mosaic when the
 default backend is a TPU, fall back to the Pallas interpreter otherwise
-(this CPU container).  ``REPRO_PALLAS_COMPILE=1`` forces compilation
-regardless of backend (useful under ``jax.experimental`` CPU lowering or
-when the backend probe is wrong).
+(CPU test runs).  An explicit ``interpret=False`` compiles for the TPU
+wherever the caller lowers (the ahead-of-time compile tests pass it).
 """
 
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
 
 
 def interpret_default() -> bool:
-    if os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1":
-        return False
     return jax.default_backend() != "tpu"
 
 

@@ -1,11 +1,16 @@
 """Pallas TPU flash attention (causal + sliding window, GQA).
 
-TPU mapping: grid = (batch, kv_head, q_blocks); each program streams KV
-blocks of shape (block_kv, head_dim) through VMEM while keeping a
+TPU mapping: grid = (batch, query_head, q_blocks); each program streams
+KV blocks of shape (block_kv, head_dim) through VMEM while keeping a
 (block_q, head_dim) query tile and fp32 accumulators resident.  Block
 shapes are multiples of 128 to align with the MXU systolic array; the
 online-softmax recurrence avoids materializing the S^2 score matrix in
 HBM (memory term: O(S * block_kv) per core instead of O(S^2)).
+
+Layout: the wrapper moves heads ahead of the sequence (``[B, H, S, hd]``
+queries, ``[B, K, T, hd]`` keys/values) so every block's last two dims
+are (sequence tile, head_dim), as the TPU (8, 128) tiling rule wants;
+GQA maps query head h to KV head ``h // G`` in the index map.
 
 Validated in interpret mode against ``repro.kernels.ref.attention_ref``.
 """
@@ -19,63 +24,60 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ._interpret import resolve_interpret
+
 NEG_INF = -1e30
 
 
 def _attn_kernel(
-    q_ref,  # [block_q, G, hd]
+    q_ref,  # [block_q, hd]
     k_ref,  # [T, hd]      (full KV stripe for this (b, kv_head))
     v_ref,  # [T, hd]
-    o_ref,  # [block_q, G, hd]
+    o_ref,  # [block_q, hd]
     *,
     block_q: int,
     block_kv: int,
     seq_len_kv: int,
     causal: bool,
     window: Optional[int],
-    q_offset_blocks: bool,
 ):
     qi = pl.program_id(2)
-    q = q_ref[...].astype(jnp.float32)  # [bq, G, hd]
-    G, hd = q.shape[1], q.shape[2]
-    scale = hd ** -0.5
-    q = q * scale
-    q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
-
-    num_kv = seq_len_kv // block_kv
+    q = q_ref[...].astype(jnp.float32)
+    hd = q.shape[-1]
+    q = q * (hd ** -0.5)
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_kv), 0)
 
     def body(ki, carry):
         m, l, acc = carry
         k = k_ref[pl.ds(ki * block_kv, block_kv), :].astype(jnp.float32)
         v = v_ref[pl.ds(ki * block_kv, block_kv), :].astype(jnp.float32)
-        kv_pos = ki * block_kv + jax.lax.iota(jnp.int32, block_kv)
+        kv_pos = ki * block_kv + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_kv), 1)
         s = jax.lax.dot_general(
-            q.reshape(block_q * G, hd), k,
-            (((1,), (1,)), ((), ())),
+            q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ).reshape(block_q, G, block_kv)
+        )
         mask = jnp.ones((block_q, block_kv), jnp.bool_)
         if causal:
-            mask = mask & (kv_pos[None, :] <= q_pos[:, None])
+            mask = mask & (kv_pos <= q_pos)
         if window is not None:
-            mask = mask & (q_pos[:, None] - kv_pos[None, :] < window)
-        s = jnp.where(mask[:, None, :], s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
+            mask = mask & (q_pos - kv_pos < window)
+        s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new[..., None])
-        l_new = l * alpha + p.sum(axis=-1)
-        acc_new = acc * alpha[..., None] + jax.lax.dot_general(
-            p.reshape(block_q * G, block_kv), v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ).reshape(block_q, G, hd)
+        p = jnp.exp(s - m_new)
+        l_new = l * alpha + p.sum(axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((block_q, G), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, G), jnp.float32)
-    a0 = jnp.zeros((block_q, G, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m0, l0, a0))
-    o_ref[...] = (acc / jnp.maximum(l, 1e-30)[..., None]).astype(o_ref.dtype)
+    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((block_q, 1), jnp.float32)
+    a0 = jnp.zeros((block_q, hd), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, seq_len_kv // block_kv, body,
+                                  (m0, l0, a0))
+    o_ref[...] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def flash_attention_pallas(
@@ -87,14 +89,14 @@ def flash_attention_pallas(
     window: Optional[int] = None,
     block_q: int = 128,
     block_kv: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,  # None = compiled on TPU, interpret on CPU
 ) -> jax.Array:
     B, S, K, G, hd = q.shape
     T = k.shape[1]
-    assert S % block_q == 0, (S, block_q)
-    assert T % block_kv == 0, (T, block_kv)
-    grid = (B, K, S // block_q)
-
+    if S % block_q or T % block_kv:
+        raise ValueError(
+            f"seq lens ({S}, {T}) must be multiples of the blocks "
+            f"({block_q}, {block_kv})")
     kernel = functools.partial(
         _attn_kernel,
         block_q=block_q,
@@ -102,17 +104,20 @@ def flash_attention_pallas(
         seq_len_kv=T,
         causal=causal,
         window=window,
-        q_offset_blocks=False,
     )
-    return pl.pallas_call(
+    qh = jnp.transpose(q.reshape(B, S, K * G, hd), (0, 2, 1, 3))
+    kh = jnp.transpose(k, (0, 2, 1, 3))
+    vh = jnp.transpose(v, (0, 2, 1, 3))
+    q_spec = pl.BlockSpec((None, None, block_q, hd),
+                          lambda b, h, i: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((None, None, T, hd),
+                           lambda b, h, i: (b, h // G, 0, 0))
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, None, G, hd), lambda b, h, i: (b, i, h, 0, 0)),
-            pl.BlockSpec((None, T, None, hd), lambda b, h, i: (b, 0, h, 0)),
-            pl.BlockSpec((None, T, None, hd), lambda b, h, i: (b, 0, h, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_q, None, G, hd), lambda b, h, i: (b, i, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, K, G, hd), q.dtype),
-        interpret=interpret,
-    )(q, k, v)
+        grid=(B, K * G, S // block_q),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, K * G, S, hd), q.dtype),
+        interpret=resolve_interpret(interpret),
+    )(qh, kh, vh)
+    return jnp.transpose(out, (0, 2, 1, 3)).reshape(B, S, K, G, hd)

@@ -1,8 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Interpret mode is auto-detected: compiled via Mosaic on TPU, Pallas
-interpreter on CPU (this container).  ``REPRO_PALLAS_COMPILE=1`` forces
-compilation; ``gossip_mix`` also takes an explicit ``interpret`` flag.
+interpreter on CPU.  ``gossip_mix`` and ``edge_segment_max`` also take
+an explicit ``interpret`` flag.
 """
 
 from __future__ import annotations
@@ -13,7 +13,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ._interpret import interpret_default as _interpret_default, resolve_interpret
 from .flash_attention import flash_attention_pallas
 from .gossip_mix import gossip_mix_pallas
 from .mlstm_scan import mlstm_scan_pallas
@@ -35,7 +34,7 @@ def flash_attention(
 ) -> jax.Array:
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv, interpret=_interpret_default(),
+        block_q=block_q, block_kv=block_kv,
     )
 
 
@@ -43,13 +42,12 @@ def flash_attention(
 def gossip_mix(neighbor_blocks: jax.Array, weights: jax.Array, *,
                block: int = 65536, interpret: Optional[bool] = None):
     return gossip_mix_pallas(neighbor_blocks, weights, block=block,
-                             interpret=resolve_interpret(interpret))
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
 def mlstm_scan(q, k, v, log_i, log_f, *, chunk: int = 128):
-    return mlstm_scan_pallas(q, k, v, log_i, log_f, chunk=chunk,
-                             interpret=_interpret_default())
+    return mlstm_scan_pallas(q, k, v, log_i, log_f, chunk=chunk)
 
 
 @functools.partial(
@@ -61,4 +59,4 @@ def edge_segment_max(vals: jax.Array, seg_ids: jax.Array, *,
                      interpret: Optional[bool] = None) -> jax.Array:
     return edge_segment_max_pallas(
         vals, seg_ids, num_segments, block=block, n_block=n_block,
-        interpret=resolve_interpret(interpret))
+        interpret=interpret)

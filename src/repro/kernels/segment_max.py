@@ -57,25 +57,26 @@ __all__ = [
 
 
 def _segmax_kernel(v_ref, i_ref, o_ref, *, n_block: int):
-    # v_ref: [1, block] values; i_ref: [1, block] int32 segment ids;
-    # o_ref: [1, n_block] running max for segment tile program_id(1).
-    # Grid is (B, S_tiles, E_tiles) with the edge axis innermost, so the
-    # output block stays resident in VMEM while edge tiles stream by.
-    e_pid = pl.program_id(2)
-
-    @pl.when(e_pid == 0)
+    # v_ref / i_ref: [block, b_block] values and int32 segment ids, edges
+    # on sublanes and graphs on lanes, so each graph's edge tile is a
+    # column that broadcasts across the segment lanes without a relayout.
+    # o_ref: [b_block, n_block] running max for segment tile
+    # program_id(1).  Grid is (B_tiles, S_tiles, E_tiles) with the edge
+    # axis innermost, so the output block stays resident in VMEM while
+    # edge tiles stream by.
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.full(o_ref.shape, NEG_INF, o_ref.dtype)
 
-    vals = v_ref[0, :]
-    ids = i_ref[0, :]
-    seg0 = pl.program_id(1) * n_block
-    seg = jax.lax.broadcasted_iota(
-        jnp.int32, (vals.shape[0], n_block), 1) + seg0
-    hit = ids[:, None] == seg
-    neg = jnp.full((), NEG_INF, vals.dtype)
-    cand = jnp.max(jnp.where(hit, vals[:, None], neg), axis=0)
-    o_ref[0, :] = jnp.maximum(o_ref[0, :], cand)
+    block, b_block = v_ref.shape
+    seg = jax.lax.broadcasted_iota(jnp.int32, (block, n_block), 1) \
+        + pl.program_id(1) * n_block
+    neg = jnp.full((), NEG_INF, o_ref.dtype)
+    for b in range(b_block):
+        hit = i_ref[:, b:b + 1] == seg
+        cand = jnp.max(jnp.where(hit, v_ref[:, b:b + 1], neg), axis=0,
+                       keepdims=True)
+        o_ref[b:b + 1, :] = jnp.maximum(o_ref[b:b + 1, :], cand)
 
 
 @contract("[B,E]", "[B,E]", "S", ret="[B,S]")
@@ -95,6 +96,11 @@ def edge_segment_max_pallas(
     inputs.  ``num_segments`` must be static; ids outside
     ``[0, num_segments)`` are dropped, matching ``segment_max``'s
     out-of-bounds scatter semantics.
+
+    Tiles obey the TPU (8, 128) rule: an axis that fits in one tile is
+    taken whole, otherwise it is padded to whole tiles of ``block``
+    edges (a multiple of 8) and ``n_block`` segments (a multiple of
+    128); more than 128 graphs are tiled 128 at a time.
     """
     interpret = resolve_interpret(interpret)
     vals = jnp.asarray(vals)
@@ -102,33 +108,34 @@ def edge_segment_max_pallas(
         raise TypeError(
             f"edge_segment_max_pallas needs a float dtype (the -inf "
             f"identity is float-only); got {vals.dtype}")
+    if block % 8 or n_block % 128:
+        raise ValueError(
+            f"block must be a multiple of 8 and n_block of 128 (TPU "
+            f"tiling); got block={block}, n_block={n_block}")
     seg_ids = jnp.asarray(seg_ids, dtype=jnp.int32)
     B, E = vals.shape
     S = int(num_segments)
     block = min(block, max(E, 1))
     n_block = min(n_block, max(S, 1))
-    e_pad = (-E) % block
-    if e_pad:
-        # Padding ids are -1: they match no segment tile and fold away.
-        vals = jnp.pad(vals, ((0, 0), (0, e_pad)),
-                       constant_values=NEG_INF)
-        seg_ids = jnp.pad(seg_ids, ((0, 0), (0, e_pad)),
-                          constant_values=-1)
-    s_pad = (-S) % n_block
-    Sp = S + s_pad
-    grid = (B, Sp // n_block, (E + e_pad) // block)
+    b_block = min(B, 128)
+    e_pad, s_pad, b_pad = (-E) % block, (-S) % n_block, (-B) % b_block
+    # Padding ids are -1: they match no segment tile and fold away.
+    vals = jnp.pad(vals.T, ((0, e_pad), (0, b_pad)), constant_values=NEG_INF)
+    seg_ids = jnp.pad(seg_ids.T, ((0, e_pad), (0, b_pad)), constant_values=-1)
+    grid = ((B + b_pad) // b_block, (S + s_pad) // n_block,
+            (E + e_pad) // block)
     out = pl.pallas_call(
         functools.partial(_segmax_kernel, n_block=n_block),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block), lambda b, j, i: (b, i)),
-            pl.BlockSpec((1, block), lambda b, j, i: (b, i)),
+            pl.BlockSpec((block, b_block), lambda b, j, i: (i, b)),
+            pl.BlockSpec((block, b_block), lambda b, j, i: (i, b)),
         ],
-        out_specs=pl.BlockSpec((1, n_block), lambda b, j, i: (b, j)),
-        out_shape=jax.ShapeDtypeStruct((B, Sp), vals.dtype),
+        out_specs=pl.BlockSpec((b_block, n_block), lambda b, j, i: (b, j)),
+        out_shape=jax.ShapeDtypeStruct((B + b_pad, S + s_pad), vals.dtype),
         interpret=interpret,
     )(vals, seg_ids)
-    return out[:, :S]
+    return out[:B, :S]
 
 
 @contract("[M]", "[M]", "S", ret="[S]")

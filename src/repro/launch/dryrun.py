@@ -26,7 +26,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config, shape_supported
-from repro.launch.mesh import make_production_mesh, mesh_context, CHIPS_PER_POD
+from repro.launch.mesh import make_production_mesh, CHIPS_PER_POD
 from repro.launch import input_specs as IS
 from repro.launch.steps import build_train_step, build_prefill_step, build_decode_step
 from repro.launch.hlo_analysis import (
@@ -170,7 +170,7 @@ def dryrun_one(
             state_ps = {"params": params_ps, "opt_state": opt_ps, "step": P()}
             state_sh = IS.named(state_ps, mesh)
             batch_sh = IS.named(batch_ps, mesh)
-            with mesh_context(mesh), activation_sharding(("data",)):
+            with jax.set_mesh(mesh), activation_sharding(("data",)):
                 lowered = jax.jit(
                     step_fn,
                     in_shardings=(state_sh, batch_sh),
@@ -186,7 +186,7 @@ def dryrun_one(
             B = spec["global_batch"]
             batch_axes = (("pod", "data") if (multi_pod and B >= 32)
                           else ("data",) if B >= 16 else None)
-            with mesh_context(mesh), activation_sharding(batch_axes):
+            with jax.set_mesh(mesh), activation_sharding(batch_axes):
                 lowered = jax.jit(
                     step_fn,
                     in_shardings=(IS.named(params_ps, mesh), IS.named(batch_ps, mesh)),
@@ -201,7 +201,7 @@ def dryrun_one(
             B = spec["global_batch"]
             batch_axes = (("pod", "data") if (multi_pod and B >= 32)
                           else ("data",) if B >= 16 else None)
-            with mesh_context(mesh), activation_sharding(batch_axes):
+            with jax.set_mesh(mesh), activation_sharding(batch_axes):
                 lowered = jax.jit(
                     step_fn,
                     in_shardings=(IS.named(params_ps, mesh), IS.named(batch_ps, mesh)),

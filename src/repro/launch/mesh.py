@@ -11,33 +11,21 @@ before any jax initialization.
 
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
-def compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` across jax versions: >= 0.6 wants explicit
-    ``axis_types``; 0.4.x has no such parameter (nor AxisType)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-def mesh_context(mesh: jax.sharding.Mesh):
-    """``jax.set_mesh`` context when available (jax >= 0.6); null context
-    on older jax, where every consumer takes the mesh explicitly."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return contextlib.nullcontext()
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode: the step
+    functions shard through ``NamedSharding``/``shard_map``, not through
+    explicit-sharding types, which ``jax.make_mesh`` defaults to."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_silo_mesh(n_silos: int, axis: str = "data") -> jax.sharding.Mesh:
@@ -51,14 +39,14 @@ def make_silo_mesh(n_silos: int, axis: str = "data") -> jax.sharding.Mesh:
     n = len(jax.devices())
     if not (1 <= n_silos <= n):
         raise ValueError(f"need 1 <= n_silos <= {n} devices, got {n_silos}")
-    return compat_make_mesh((n_silos,), (axis,))
+    return _auto_mesh((n_silos,), (axis,))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over the locally available devices (CPU tests/examples)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return compat_make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis.

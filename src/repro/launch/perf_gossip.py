@@ -20,7 +20,7 @@ from repro.configs import get_config
 from repro.fed import DPASGDConfig, make_train_step
 from repro.fed.topology_runtime import plan_for_n_silos
 from repro.launch import input_specs as IS
-from repro.launch.mesh import make_production_mesh, mesh_context
+from repro.launch.mesh import make_production_mesh
 from repro.launch.hlo_analysis import collective_bytes, _COLLECTIVES
 from repro.models import SILO_TP, transformer as T
 from repro.models.act_sharding import activation_sharding
@@ -54,7 +54,7 @@ def run_one(gossip_kind: str, gossip_impl: str = "ppermute"):
     state_abs = {"params": params_abs, "opt_state": opt_abs,
                  "step": jax.ShapeDtypeStruct((), jnp.int32)}
     state_ps = {"params": params_ps, "opt_state": opt_ps, "step": P()}
-    with mesh_context(mesh), activation_sharding(None):
+    with jax.set_mesh(mesh), activation_sharding(None):
         compiled = jax.jit(
             step_fn,
             in_shardings=(IS.named(state_ps, mesh), IS.named(batch_ps, mesh)),

@@ -3,9 +3,14 @@
     PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
         --reduced --silos 4 --topology ring --steps 50
 
-On this CPU container use ``--reduced`` (tiny same-family variant) and a
-virtual device mesh (set automatically from --silos).  On TPU the same
-entry point drives the production mesh.
+On the CPU (``JAX_PLATFORMS=cpu``) use ``--reduced`` (tiny same-family
+variant); the virtual device count is set from ``--silos``.  On a TPU
+the same entry point puts one silo on each chip; ``--layers k`` cuts the
+depth to whole periods of the published layer pattern while every width
+stays published, e.g. one silo of internlm2-1.8b on one v5e chip:
+
+    PYTHONPATH=src python -m repro.launch.train --arch internlm2-1.8b \
+        --layers 4 --silos 1 --seq-len 2048 --batch-per-silo 4 --steps 5
 
 ``--dynamic`` attaches the online topology controller: the WAN between
 the silos is simulated from a real underlay (``--underlay``) through a
@@ -45,10 +50,20 @@ import sys
 import time
 
 
-def main() -> int:
+def main(argv=None, report=None) -> int:
+    """Run the trainer on ``argv`` (default: ``sys.argv[1:]``).
+
+    ``report``, when a dict, receives what an in-process caller checks:
+    ``losses`` (each step's mean loss, read back once after the last
+    step), ``state`` (the final train state, still on its devices),
+    ``mesh`` and ``plan``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="internlm2-1.8b")
-    ap.add_argument("--reduced", action="store_true")
+    depth = ap.add_mutually_exclusive_group()
+    depth.add_argument("--reduced", action="store_true")
+    depth.add_argument("--layers", type=int, default=0,
+                       help="depth cut; widths stay published (a multiple "
+                            "of the arch's layer-pattern period)")
     ap.add_argument("--silos", type=int, default=4)
     ap.add_argument("--topology", default="ring",
                     choices=["ring", "star", "chain", "none", "mst",
@@ -120,7 +135,7 @@ def main() -> int:
                          "bit-identical and joiners sit at the consensus "
                          "average (full-model host sweep: acceptance "
                          "tests/debugging, not production loops)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     underlay = None
     silo_names = None
@@ -138,7 +153,9 @@ def main() -> int:
         if sites is not None:
             silo_names = [name for name, _ in sites]
 
-    if "XLA_FLAGS" not in os.environ:
+    if os.environ.get("JAX_PLATFORMS") == "cpu" and "XLA_FLAGS" not in os.environ:
+        # virtual CPU devices, one per silo; on an accelerator the silos
+        # map onto the real chips and a missing backend is an error
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={max(args.silos, 1)}")
 
@@ -155,7 +172,8 @@ def main() -> int:
         DPASGDConfig, init_state, make_train_step, migrate_silo_state,
         slice_silo_row,
     )
-    from repro.launch.mesh import make_silo_mesh, mesh_context
+    from repro.launch.compile_cache import enable_compile_cache
+    from repro.launch.mesh import make_silo_mesh
     from repro.fed.topology_runtime import plan_for_n_silos, plan_from_overlay
     from repro.obs import enable as obs_enable, span, summary as span_summary
     from repro.obs import metrics as obs_metrics
@@ -164,6 +182,10 @@ def main() -> int:
     from repro.optim import momentum
 
     log = get_logger("train")
+    enable_compile_cache()
+    devices = jax.devices()
+    print(f"devices: {devices[0].platform} {devices[0].device_kind} "
+          f"x{len(devices)}", flush=True)
     recorder = None
     if args.trace_out:
         obs_enable()
@@ -180,7 +202,8 @@ def main() -> int:
         )
         log.info("trace", path=args.trace_out)
 
-    cfg = get_config(args.arch)
+    cfg = (get_config(args.arch, n_layers=args.layers) if args.layers
+           else get_config(args.arch))
     if args.reduced:
         cfg = cfg.reduced()
     import dataclasses
@@ -329,14 +352,15 @@ def main() -> int:
                          requested=args.topology, used=kind)
             plan = plan_for_n_silos(kind, n) if n > 1 else None
 
-    def shard_state(state_host, mesh):
-        def put(x):
-            if getattr(x, "ndim", 0) > 0:
-                return jax.device_put(x, NamedSharding(
-                    mesh, P(*(("data",) + (None,) * (x.ndim - 1)))))
-            return x
+    def silo_shardings(state, mesh):
+        # silo-stacked leaves split their leading dim over the silo axis
+        # (one silo per device); the shared step counter is replicated
+        return jax.tree_util.tree_map(
+            lambda x: NamedSharding(mesh, P(*(("data",) + (None,) * (x.ndim - 1)))
+                                    if x.ndim else P()), state)
 
-        return jax.tree_util.tree_map(put, state_host)
+    def shard_state(state_host, mesh):
+        return jax.device_put(state_host, silo_shardings(state_host, mesh))
 
     # Recompile accounting: TraceCounter wraps the *pre-jit* step body, so
     # its count moves exactly when jax re-traces (initial lowering or a
@@ -351,21 +375,28 @@ def main() -> int:
     trace_counters: list = []
     step_fn = make_counted_step(cfg, fed, opt, plan, mesh,
                                 consensus_arg=sched_mode)
-    state = init_state(cfg, opt, jax.random.PRNGKey(0))
-    if n > 1:
-        state = shard_state(state, mesh)
+
+    def make_state(key):
+        return init_state(cfg, opt, key)
+
+    key = jax.random.PRNGKey(0)
+    # built where it lives: each silo's rows are initialised on its own
+    # device, never gathered on the first one
+    state = jax.jit(make_state, out_shardings=silo_shardings(
+        jax.eval_shape(make_state, key), mesh))(key)
     # The data stream spans the full silo universe: under elastic
     # membership each silo label keeps its own (non-iid) distribution
     # across leaves/rejoins; the batcher stacks only the active labels.
     stream = SyntheticLMStream(cfg.vocab_size, args.seq_len, n_silos=max(n, 1))
     batcher = FederatedBatcher(stream, args.local_steps, args.batch_per_silo)
-    jstep = jax.jit(step_fn)
+    jstep = jax.jit(step_fn, donate_argnums=0)
     built_version = slot.version if slot is not None else 0
     built_mem_version = mem_slot.version if mem_slot is not None else 0
     active = tuple(range(n))
+    losses = []
     t0 = time.time()
     with contextlib.ExitStack() as mesh_stack:
-        mesh_stack.enter_context(mesh_context(mesh))
+        mesh_stack.enter_context(jax.set_mesh(mesh))
         for i in range(args.steps):
             if args.dynamic:
                 # one train step == one communication round of simulated
@@ -404,6 +435,8 @@ def main() -> int:
             else:
                 with span("train.step"):
                     state, metrics = jstep(state, b)
+            if report is not None:
+                losses.append(metrics["loss"])
             if args.dynamic:
                 redesign = controller.observe_round(duration)
                 if redesign is not None:
@@ -447,12 +480,12 @@ def main() -> int:
                     cfg = dataclasses.replace(cfg, n_silos=n)
                     mesh = make_silo_mesh(n)
                     mesh_stack.close()
-                    mesh_stack.enter_context(mesh_context(mesh))
+                    mesh_stack.enter_context(jax.set_mesh(mesh))
                     state = shard_state(state_host, mesh)
                     jstep = jax.jit(make_counted_step(
                         cfg, fed, opt,
                         None if sched_mode else slot.plan, mesh,
-                        consensus_arg=sched_mode))
+                        consensus_arg=sched_mode), donate_argnums=0)
                     built_version = slot.version if slot is not None else 0
                     built_mem_version = mem_slot.version
                     msg = (f"step {i:4d} membership v{mem_slot.version}: "
@@ -491,7 +524,8 @@ def main() -> int:
                 if slot is not None and slot.version != built_version:
                     # hot-swap: re-lower the train step on the new plan
                     jstep = jax.jit(make_counted_step(cfg, fed, opt,
-                                                      slot.plan, mesh))
+                                                      slot.plan, mesh),
+                                    donate_argnums=0)
                     built_version = slot.version
                 # sched_slot swaps need no re-lowering: the consensus
                 # matrix is a traced input, matrix_for_round follows the
@@ -547,6 +581,10 @@ def main() -> int:
         )
         log.info("trace-written", path=args.trace_out,
                  spans=len(span_summary()))
+    if report is not None:
+        report.update(losses=[float(x) for x in jax.device_get(losses)],
+                      state=state, mesh=mesh,
+                      plan=slot.plan if slot is not None else plan)
     return 0
 
 

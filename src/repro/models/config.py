@@ -149,6 +149,26 @@ class ModelConfig:
             return False
         return True
 
+    @property
+    def layer_period(self) -> int:
+        """Smallest p such that every layer i has the same block kind and
+        attention span (windowed or global) as layer ``i % p``."""
+        kinds = [(b, self.layer_uses_window(i))
+                 for i, b in enumerate(self.block_pattern)]
+        return next(p for p in range(1, self.n_layers + 1)
+                    if all(k == kinds[i % p] for i, k in enumerate(kinds)))
+
+    def with_depth(self, n_layers: int) -> "ModelConfig":
+        """The first ``n_layers`` layers, a whole number of layer-pattern
+        periods, with every width as published (a one-chip depth cut)."""
+        p = self.layer_period
+        if not (0 < n_layers <= self.n_layers and n_layers % p == 0):
+            raise ValueError(
+                f"{self.arch_id}: depth cut to {n_layers} layers must be a "
+                f"multiple of its layer period {p}, at most {self.n_layers}")
+        return dataclasses.replace(
+            self, n_layers=n_layers, block_pattern=self.block_pattern[:n_layers])
+
     def reduced(self, *, n_layers: int = 2, d_model: int = 256) -> "ModelConfig":
         """A tiny same-family variant for CPU smoke tests."""
         scale = d_model / self.d_model

@@ -27,11 +27,7 @@ def small_cfg(n_silos):
     return ModelConfig("tiny", "dense", 2, 64, 2, 2, 128, 256, n_silos=n_silos)
 
 
-from repro.launch.mesh import compat_make_mesh, mesh_context as mesh_ctx
-
-
-def make_mesh(n):
-    return compat_make_mesh((n,), ("data",))
+from repro.launch.mesh import make_silo_mesh as make_mesh
 
 
 def shard_state(state, mesh):
@@ -53,7 +49,7 @@ def check_gossip_impls_agree():
     for kind in ("ring", "star", "chain"):
         plan = plan_for_n_silos(kind, n)
         A = jnp.asarray(plan.matrix)
-        with mesh_ctx(mesh):
+        with jax.set_mesh(mesh):
             ein = gossip_einsum(params, A)
             ppm = gossip_shard_map(params, plan, mesh, "data")
             pal = gossip_shard_map(params, plan, mesh, "data", use_pallas=True)
@@ -79,7 +75,7 @@ def check_dpasgd_trains_and_converges():
     batcher = FederatedBatcher(stream, local_steps=2, batch_per_silo=4)
     jstep = jax.jit(step_fn)
     losses = []
-    with mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         for i in range(8):
             b = {k: jnp.asarray(v) for k, v in batcher.batch(i).items()}
             state, m = jstep(state, b)
@@ -114,7 +110,7 @@ def check_full_mixing_equals_single_worker():
     one = stream.sample(0, 4, 0)
     batch = {k: jnp.broadcast_to(jnp.asarray(v)[None, None], (n, 1) + v.shape)
              for k, v in one.items()}
-    with mesh_ctx(mesh):
+    with jax.set_mesh(mesh):
         state, _ = jax.jit(step_fn)(state, batch)
     from repro.fed.dpasgd import local_sgd_steps, make_loss_fn
 

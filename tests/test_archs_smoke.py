@@ -136,3 +136,26 @@ def test_param_counts_in_family_range():
         cfg = get_config(arch)
         n = count_params(TT.model_specs(cfg))
         assert lo <= n <= hi, f"{arch}: {n / 1e9:.2f}B params out of [{lo/1e9},{hi/1e9}]"
+
+
+@pytest.mark.parametrize("arch,period,cut", [
+    ("internlm2-1.8b", 1, 4),    # all "attn"
+    ("xlstm-350m", 6, 12),       # sLSTM at every 6th position
+    ("hymba-1.5b", 16, 16),      # full attention every 16th layer
+])
+def test_depth_cut_keeps_whole_periods_and_published_widths(arch, period, cut):
+    full = get_config(arch)
+    assert full.layer_period == period
+    cfg = get_config(arch, n_layers=cut)
+    assert cfg.n_layers == cut
+    assert cfg.block_pattern == full.block_pattern[:cut]
+    assert [cfg.layer_uses_window(i) for i in range(cut)] == \
+        [full.layer_uses_window(i) for i in range(cut)]
+    widths = ("d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
+              "vocab_size", "sliding_window", "ssm", "moe", "mla")
+    assert all(getattr(cfg, w) == getattr(full, w) for w in widths)
+    if period > 1:
+        with pytest.raises(ValueError, match="layer period"):
+            get_config(arch, n_layers=period + 1)
+    with pytest.raises(ValueError, match="layer period"):
+        get_config(arch, n_layers=full.n_layers + period)

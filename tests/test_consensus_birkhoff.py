@@ -3,7 +3,7 @@ collective-schedule bridge)."""
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.birkhoff import birkhoff_decomposition, reconstruct, schedule_cost
 from repro.core.consensus import (

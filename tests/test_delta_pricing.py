@@ -5,7 +5,7 @@ reconnect the graph."""
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as C
 from repro.core.maxplus_sparse import (

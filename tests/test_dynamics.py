@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as C
 from repro.core.delays import TrainingParams, overlay_delay_matrix

@@ -4,7 +4,7 @@ the paper's worked examples (Appendix C)."""
 import math
 
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.maxplus import (
     DelayDigraph,

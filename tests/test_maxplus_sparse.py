@@ -13,7 +13,7 @@ import random
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as C
 from repro.core.delays import batched_overlay_delay_matrices

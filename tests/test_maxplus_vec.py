@@ -12,7 +12,7 @@ import random
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.maxplus import (
     DelayDigraph,

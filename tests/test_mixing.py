@@ -30,7 +30,7 @@ import math
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as C
 from repro.core.consensus import (

@@ -4,7 +4,7 @@ Karp path it competes with."""
 
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 pytest.importorskip("jax")
 
@@ -31,18 +31,20 @@ def _ref_flat(vals, ids, S):
 
 
 @settings(max_examples=20, deadline=None)
-@given(st.integers(1, 4), st.integers(1, 90), st.integers(1, 40),
+@given(st.integers(1, 4), st.integers(1, 90), st.integers(1, 300),
        st.integers(0, 2 ** 31 - 1))
 def test_edge_segment_max_bit_identical(B, E, S, seed):
     """Random values (including -inf entries and out-of-range ids) match
-    vmapped ``jax.ops.segment_max`` bit for bit, empty segments included."""
+    vmapped ``jax.ops.segment_max`` bit for bit, empty segments included,
+    with several edge tiles and (past 128 segments) several segment
+    tiles."""
     rng = np.random.default_rng(seed)
     vals = rng.standard_normal((B, E)).astype(np.float32)
     vals[rng.random((B, E)) < 0.15] = -np.inf
     # ids in [-1, S]: -1 and S are out of range and must be dropped,
     # exactly like segment_max's out-of-bounds scatter semantics.
     ids = rng.integers(-1, S + 1, size=(B, E)).astype(np.int32)
-    got = edge_segment_max_pallas(vals, ids, S, block=32, n_block=16,
+    got = edge_segment_max_pallas(vals, ids, S, block=32, n_block=128,
                                   interpret=True)
     want = jax.vmap(lambda v, i: _ref_flat(v, i, S))(
         jnp.asarray(vals), jnp.asarray(ids))
@@ -145,3 +147,23 @@ def test_padded_layout_drops_absent_arcs_before_ranking():
     got = batched_cycle_time_sparse_jax(src, dst, w, n, kernel="padded",
                                         max_in_degree=2)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+def test_many_graphs_tile_128_at_a_time():
+    """Past 128 graphs the batch axis is tiled (and padded) 128 at a
+    time; every row still matches ``jax.ops.segment_max`` bit for bit."""
+    rng = np.random.default_rng(7)
+    vals = rng.standard_normal((130, 24)).astype(np.float32)
+    ids = rng.integers(-1, 6, size=(130, 24)).astype(np.int32)
+    got = edge_segment_max_pallas(vals, ids, 5, interpret=True)
+    want = jax.vmap(lambda v, i: _ref_flat(v, i, 5))(
+        jnp.asarray(vals), jnp.asarray(ids))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("block,n_block", [(12, 128), (16, 100)])
+def test_tiles_off_the_tpu_grid_rejected(block, n_block):
+    with pytest.raises(ValueError, match="TPU tiling"):
+        edge_segment_max_pallas(np.ones((1, 4), np.float32),
+                                np.zeros((1, 4), np.int32), 3,
+                                block=block, n_block=n_block, interpret=True)

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.optim import sgd, momentum, adam, adamw, clip_by_global_norm
 from repro.optim.optimizers import inverse_sqrt_decay

@@ -5,7 +5,7 @@ import math
 import random
 
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import repro.core as C
 from repro.core.delays import ConnectivityGraph, SiloParams, TrainingParams
