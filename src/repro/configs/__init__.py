@@ -45,25 +45,3 @@ def get_config(arch_id: str, **overrides) -> ModelConfig:
     if overrides:
         cfg = dataclasses.replace(cfg, **overrides)
     return cfg
-
-
-# ---------------------------------------------------------------------------
-# Input shapes of the assignment.
-
-INPUT_SHAPES = {
-    "train_4k": dict(seq_len=4_096, global_batch=256, kind="train"),
-    "prefill_32k": dict(seq_len=32_768, global_batch=32, kind="prefill"),
-    "decode_32k": dict(seq_len=32_768, global_batch=128, kind="decode"),
-    "long_500k": dict(seq_len=524_288, global_batch=1, kind="decode"),
-}
-
-
-def long_context_supported(cfg: ModelConfig) -> bool:
-    """long_500k requires sub-quadratic decode (see DESIGN.md §4)."""
-    return cfg.is_subquadratic
-
-
-def shape_supported(cfg: ModelConfig, shape_name: str) -> bool:
-    if shape_name == "long_500k":
-        return long_context_supported(cfg)
-    return True

@@ -1,8 +1,8 @@
 """internvl2-76b [vlm]: InternViT + LLM backbone [arXiv:2404.16821].
 Backbone: 80L d_model=8192 64H (GQA kv=8) d_ff=28672 vocab=128256.
 The vision encoder is a STUB per the assignment carve-out:
-``input_specs`` supplies 256 precomputed patch embeddings (dim 1024)
-that a learned projector maps into the LM embedding space."""
+the model takes 256 precomputed patch embeddings (dim 1024), which a
+learned projector maps into the LM embedding space."""
 
 from repro.models import ModelConfig
 

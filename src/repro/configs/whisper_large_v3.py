@@ -1,8 +1,8 @@
 """whisper-large-v3 [audio]: encoder-decoder [arXiv:2212.04356].
 32L decoder + 32L encoder, d_model=1280 20H d_ff=5120 vocab=51866.
 The mel-spectrogram + conv frontend is a STUB per the assignment
-carve-out: ``input_specs`` supplies 1500 precomputed frame features
-(dim 128) consumed by a learned projection."""
+carve-out: the model takes 1500 precomputed frame features (dim 128),
+consumed by a learned projection."""
 
 from repro.models import ModelConfig
 from repro.models.config import EncoderConfig

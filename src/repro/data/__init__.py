@@ -1,2 +1,2 @@
-from .pipeline import SyntheticLMStream, FederatedBatcher, make_batch_specs
+from .pipeline import SyntheticLMStream, FederatedBatcher
 from .partition import dirichlet_vocab_partition, lognormal_sizes

@@ -10,11 +10,9 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Iterator, Optional, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.models import ModelConfig
+from repro.obs import span
 from .partition import dirichlet_vocab_partition
 
 
@@ -79,40 +77,21 @@ class FederatedBatcher:
         the mesh hosts only the active silos, but each silo label keeps
         its own data distribution across leaves/rejoins (row k of the
         batch is silo ``silos[k]``, not "the k-th mesh position's
-        stream").  Default: every silo, in label order."""
-        s, B = self.local_steps, self.batch_per_silo
-        labels = tuple(range(self.stream.n_silos)) if silos is None else tuple(silos)
-        per_silo = []
-        for i in labels:
-            if not (0 <= i < self.stream.n_silos):
-                raise ValueError(
-                    f"silo {i} outside stream universe 0..{self.stream.n_silos - 1}"
+        stream").  Default: every silo, in label order.  Timed as the
+        ``input.batch`` span."""
+        with span("input.batch", step=step):
+            s, B = self.local_steps, self.batch_per_silo
+            labels = tuple(range(self.stream.n_silos)) if silos is None else tuple(silos)
+            per_silo = []
+            for i in labels:
+                if not (0 <= i < self.stream.n_silos):
+                    raise ValueError(
+                        f"silo {i} outside stream universe 0..{self.stream.n_silos - 1}"
+                    )
+                micro = [self.stream.sample(i, B, step * s + m) for m in range(s)]
+                per_silo.append(
+                    {k: np.stack([m[k] for m in micro]) for k in micro[0]}
                 )
-            micro = [self.stream.sample(i, B, step * s + m) for m in range(s)]
-            per_silo.append(
-                {k: np.stack([m[k] for m in micro]) for k in micro[0]}
-            )
-        if self.stream.n_silos == 1 and silos is None:
-            return per_silo[0]
-        return {k: np.stack([ps[k] for ps in per_silo]) for k in per_silo[0]}
-
-
-def make_batch_specs(
-    cfg: ModelConfig,
-    global_batch: int,
-    seq_len: int,
-    local_steps: int,
-    *,
-    dtype=jnp.int32,
-) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for a DPASGD training batch (used by the
-    dry-run; mirrors ``input_specs``)."""
-    n = cfg.n_silos
-    per = global_batch // max(n, 1)
-    lead: Tuple[int, ...] = (n, local_steps) if n > 1 else (local_steps,)
-    shape = lead + (per, seq_len)
-    out = {
-        "tokens": jax.ShapeDtypeStruct(shape, dtype),
-        "labels": jax.ShapeDtypeStruct(shape, dtype),
-    }
-    return out
+            if self.stream.n_silos == 1 and silos is None:
+                return per_silo[0]
+            return {k: np.stack([ps[k] for ps in per_silo]) for k in per_silo[0]}

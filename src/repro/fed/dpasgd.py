@@ -6,7 +6,7 @@ its overlay in-neighbours through the consensus matrix A:
     w_i(k+1) = sum_{j in N_i^+ u {i}} A_ij w_j(k)        (mix rounds)
     w_i(k+1) = w_i(k) - alpha * grad f_i(w_i(k))          (local rounds)
 
-Federation axes (see DESIGN.md §3):
+Federation axes:
 * ``n_silos == 1``      — degenerate: centralized data-parallel training
                           (the STAR-inside-one-pod baseline).
 * ``n_silos == |axis|`` — every index of the silo mesh axis ("data" on a
@@ -51,8 +51,12 @@ class DPASGDConfig:
 
 
 def make_loss_fn(cfg: ModelConfig):
+    """The loss a silo differentiates, under the ``forward`` scope: its
+    ops carry ``jvp(forward)`` in their HLO ``op_name``, and the backward
+    pass's (recomputed ops included) ``transpose(jvp(forward))``."""
     def loss(params, batch):
-        return T.loss_fn(params, cfg, batch)
+        with jax.named_scope("forward"):
+            return T.loss_fn(params, cfg, batch)
 
     return loss
 
@@ -198,7 +202,8 @@ def local_sgd_steps(
             l = l / accum_steps
         else:
             l, g = jax.value_and_grad(loss_fn)(p, micro)
-        p, o = optimizer.update(g, o, p, st)
+        with jax.named_scope("optimizer"):
+            p, o = optimizer.update(g, o, p, st)
         return (p, o, st + 1), l
 
     (params, opt_state, step), losses = jax.lax.scan(

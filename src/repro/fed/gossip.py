@@ -307,10 +307,11 @@ def gossip_einsum(params: Any, A: jax.Array) -> Any:
     with every leaf replaced by ``einsum('ij,j...->i...', A, leaf)`` —
     XLA lowers this to an all-gather over the silo axis, so its traffic
     is overlay-independent (the naive baseline the ppermute schedule
-    beats)."""
-    return jax.tree_util.tree_map(
-        lambda w: jnp.einsum("ij,j...->i...", A.astype(w.dtype), w), params
-    )
+    beats).  Its ops run under the ``gossip`` scope."""
+    with jax.named_scope("gossip"):
+        return jax.tree_util.tree_map(
+            lambda w: jnp.einsum("ij,j...->i...", A.astype(w.dtype), w), params
+        )
 
 
 def _perm_to_pairs(perm: Sequence[int]) -> List[Tuple[int, int]]:
@@ -332,6 +333,7 @@ def gossip_shard_map(
 
     ``params`` leaves have a leading silo dim of size n_silos sharded over
     ``axis`` (plus whatever ``extra_spec`` shards the remaining dims).
+    The transfers and the mix run under the ``gossip`` scope.
     """
     ident = tuple(range(plan.n_silos))
 
@@ -359,7 +361,8 @@ def gossip_shard_map(
     in_spec = jax.tree_util.tree_unflatten(treedef, specs)
     fn = jax.shard_map(mix_tree, mesh=mesh, in_specs=(in_spec,),
                        out_specs=in_spec, check_vma=False)
-    return fn(params)
+    with jax.named_scope("gossip"):
+        return fn(params)
 
 
 def _pallas_mix_tree(
@@ -396,6 +399,7 @@ def _pallas_mix_tree(
 
 
 def collective_bytes_per_round(plan: GossipPlan, param_bytes: int) -> int:
-    """Predicted gossip traffic per communication round per silo — used to
-    cross-check the HLO-derived collective bytes in the roofline."""
+    """Predicted gossip traffic per communication round per silo, to
+    set against the compiled step's count
+    (``launch/hlo_analysis.collective_bytes``)."""
     return plan.num_transfers * param_bytes

@@ -1,15 +1,11 @@
-"""Analytic FLOP model — exact matmul accounting per (arch x shape).
+"""Analytic FLOP model — exact matmul accounting of a forward pass.
 
-Cross-checks the HLO-derived compute term: XLA's cost_analysis counts a
-while-loop body once, so models with non-unrolled scans (mLSTM chunks,
-sLSTM/mamba time steps) under-count in the HLO number; this model counts
-every matmul from the known shapes.  Backward pass = 2x forward;
-rematerialization adds ~1 extra forward for checkpointed blocks.
+XLA's cost_analysis counts a while-loop body once, so models with
+non-unrolled scans (mLSTM chunks, sLSTM/mamba time steps) under-count in
+the HLO number; this model counts every matmul from the known shapes.
 """
 
 from __future__ import annotations
-
-from typing import Dict
 
 from repro.models import ModelConfig
 
@@ -104,17 +100,3 @@ def forward_flops(cfg: ModelConfig, S: int, T: int, *, decode: bool = False) -> 
             total += _layer_flops(cfg, "attn", layer, Tenc, Tenc, False)
     total += 2 * S * cfg.d_model * cfg.padded_vocab_size  # lm head
     return total
-
-
-def analytic_step_flops(cfg: ModelConfig, shape_spec: Dict, kind: str) -> float:
-    """Whole-step FLOPs across the global batch (all silos)."""
-    S, B = shape_spec["seq_len"], shape_spec["global_batch"]
-    if kind == "train":
-        S_tok = S - cfg.vision_prefix_len
-        fwd = forward_flops(cfg, S, S)
-        # bwd = 2x fwd; remat recompute ~= +1 fwd
-        mult = 3.0 + (1.0 if cfg.remat else 0.0)
-        return mult * fwd * B
-    if kind == "prefill":
-        return forward_flops(cfg, S, S) * B
-    return forward_flops(cfg, 1, S, decode=True) * B

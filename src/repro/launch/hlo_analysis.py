@@ -1,27 +1,15 @@
-"""Roofline-term extraction from compiled dry-run artifacts.
-
-* FLOPs / HBM bytes: ``compiled.cost_analysis()``.
-* Collective bytes: parsed from the optimized HLO text — sum of the
-  output-shape bytes of every all-gather / all-reduce / reduce-scatter /
-  all-to-all / collective-permute (the standard per-device wire-volume
-  approximation).
-
-Terms (per step, whole mesh; TPU v5e constants from launch.mesh):
-
-    compute    = HLO_FLOPs / (chips * 197e12)
-    memory     = HLO_bytes / (chips * 819e9)
-    collective = collective_bytes / (chips * 50e9)
+"""Collective bytes of a compiled program, from its optimized HLO text:
+the output-shape bytes of every all-gather / all-reduce / reduce-scatter
+/ all-to-all / collective-permute summed over the module (the standard
+per-device wire-volume approximation).  An async pair counts once, by
+its ``-done``, whose output is the result; the ``-start``'s output tuple
+holds the operand and the result again.
 """
 
 from __future__ import annotations
 
-import json
-import math
 import re
-from dataclasses import dataclass, asdict
-from typing import Dict, List, Optional, Tuple
-
-from .mesh import PEAK_FLOPS_BF16, HBM_BW, ICI_BW
+from typing import Dict
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -56,7 +44,8 @@ def _shape_bytes(shape_str: str) -> int:
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
-    """Per-collective-kind output bytes summed over the module."""
+    """Per-collective-kind output bytes summed over the module, and
+    ``collective-count``."""
     out = {k: 0 for k in _COLLECTIVES}
     out["collective-count"] = 0
     for line in hlo_text.splitlines():
@@ -66,100 +55,11 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
         if not m:
             continue
         shape_str, op = m.group(1), m.group(2)
+        if op.endswith("-start"):
+            continue
         for kind in _COLLECTIVES:
             if op == kind or op.startswith(kind + "-"):
                 out[kind] += _shape_bytes(shape_str)
                 out["collective-count"] += 1
                 break
     return out
-
-
-@dataclass
-class Roofline:
-    arch: str
-    shape: str
-    mesh: str
-    chips: int
-    hlo_gflops: float          # whole-mesh FLOPs per step / 1e9
-    hlo_gbytes: float          # whole-mesh HBM bytes per step / 1e9
-    coll_gbytes: float         # whole-mesh collective bytes / 1e9
-    compute_ms: float
-    memory_ms: float
-    collective_ms: float
-    bottleneck: str
-    model_gflops: float        # 6*N*D (or 6*N_active*D) useful FLOPs
-    useful_flop_ratio: float   # model / hlo
-    analytic_gflops: float     # exact matmul accounting (whole mesh)
-    analytic_compute_ms: float
-    bytes_per_device: int      # peak from memory_analysis
-    collective_breakdown: Dict[str, int]
-
-    def to_json(self) -> str:
-        return json.dumps(asdict(self), indent=2)
-
-
-def make_roofline(
-    *,
-    arch: str,
-    shape: str,
-    mesh_name: str,
-    chips: int,
-    cost: Dict[str, float],
-    hlo_text: str,
-    peak_bytes_per_device: int,
-    model_flops: float,
-    cost_scale: float = 1.0,
-    analytic_flops: float = 0.0,
-) -> Roofline:
-    # cost_analysis flops/bytes are per-device for SPMD modules.
-    # cost_scale corrects XLA's count-while-body-once accounting for the
-    # outer (local_steps x grad-accum) scan; inner attention/mlstm scans
-    # are fully unrolled at analysis time (cfg.analysis_unroll).
-    flops = float(cost.get("flops", 0.0)) * cost_scale
-    bytes_accessed = float(cost.get("bytes accessed", 0.0)) * cost_scale
-    coll = collective_bytes(hlo_text)
-    coll = {k: (int(v * cost_scale) if k != "collective-count" else v)
-            for k, v in coll.items()}
-    coll_total = sum(v for k, v in coll.items() if k != "collective-count")
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = bytes_accessed / HBM_BW
-    collective_s = coll_total / ICI_BW
-    analytic_compute_s = (analytic_flops / chips) / PEAK_FLOPS_BF16
-    # dominant term: compute judged on max(HLO, analytic) — non-unrolled
-    # scan bodies make the HLO flop count a lower bound (see module doc)
-    terms = {"compute": max(compute_s, analytic_compute_s),
-             "memory": memory_s, "collective": collective_s}
-    bottleneck = max(terms, key=terms.get)
-    total_flops = flops * chips
-    return Roofline(
-        arch=arch,
-        shape=shape,
-        mesh=mesh_name,
-        chips=chips,
-        hlo_gflops=total_flops / 1e9,
-        hlo_gbytes=bytes_accessed * chips / 1e9,
-        coll_gbytes=coll_total * chips / 1e9,
-        compute_ms=compute_s * 1e3,
-        memory_ms=memory_s * 1e3,
-        collective_ms=collective_s * 1e3,
-        bottleneck=bottleneck,
-        model_gflops=model_flops / 1e9,
-        useful_flop_ratio=(model_flops / total_flops) if total_flops else 0.0,
-        analytic_gflops=analytic_flops / 1e9,
-        analytic_compute_ms=analytic_compute_s * 1e3,
-        bytes_per_device=peak_bytes_per_device,
-        collective_breakdown=coll,
-    )
-
-
-def model_flops_estimate(cfg, shape_spec: Dict, n_params_active: float,
-                         kind: str) -> float:
-    """6*N*D for training, 2*N*D for inference forward (per step)."""
-    if kind == "train":
-        tokens = shape_spec["seq_len"] * shape_spec["global_batch"]
-        return 6.0 * n_params_active * tokens
-    if kind == "prefill":
-        tokens = shape_spec["seq_len"] * shape_spec["global_batch"]
-        return 2.0 * n_params_active * tokens
-    # decode: one token per sequence
-    return 2.0 * n_params_active * shape_spec["global_batch"]

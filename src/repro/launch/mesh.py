@@ -1,12 +1,9 @@
-"""Production meshes.
-
-Single pod: 256 chips as (16, 16) over ("data", "model").
-Multi-pod:  2 pods x 256 chips as (2, 16, 16) over ("pod", "data", "model");
-the "pod" axis carries the DPASGD silo replicas (DESIGN.md §3).
+"""Device meshes: one silo per device index (``make_silo_mesh``), or a
+small ("data", "model") mesh over the local devices.
 
 Defined as FUNCTIONS so importing this module never touches jax device
-state — the dry-run sets ``xla_force_host_platform_device_count=512``
-before any jax initialization.
+state — ``launch/train.py`` sets ``xla_force_host_platform_device_count``
+on the CPU before any jax initialization.
 """
 
 from __future__ import annotations
@@ -20,12 +17,6 @@ def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
     explicit-sharding types, which ``jax.make_mesh`` defaults to."""
     return jax.make_mesh(
         shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
 
 
 def make_silo_mesh(n_silos: int, axis: str = "data") -> jax.sharding.Mesh:
@@ -48,10 +39,3 @@ def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     assert data * model <= n, (data, model, n)
     return _auto_mesh((data, model), ("data", "model"))
 
-
-# TPU v5e hardware constants used by the roofline analysis.
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
-CHIPS_PER_POD = 256
-HBM_BYTES = 16 * 1024 ** 3    # 16 GiB per chip

@@ -405,11 +405,12 @@ def main(argv=None, report=None) -> int:
                 # round actually spans — a silo departing mid-round is
                 # masked out of this very round's mix, not the next one's.
                 duration = timeline.step()
-            raw = batcher.batch(i, silos=active if args.dynamic else None)
+            with span("train.input", step=i):
+                raw = batcher.batch(i, silos=active if args.dynamic else None)
+                b = {k: jnp.asarray(v) for k, v in raw.items()}
             if recorder is not None:
                 obs_metrics.counter("train.h2d_bytes").inc(
                     sum(getattr(v, "nbytes", 0) for v in raw.values()))
-            b = {k: jnp.asarray(v) for k, v in raw.items()}
             if sched_mode:
                 # per-round sampled consensus: traced argument, same
                 # compiled step for every sampled topology
@@ -427,13 +428,13 @@ def main(argv=None, report=None) -> int:
                         print(f"step {i:4d} consensus masked to "
                               f"{n_act}/{len(active)} silos "
                               f"(mid-round churn)", flush=True)
-                    with span("train.step"):
+                    with span("train.dispatch", step=i):
                         state, metrics = jstep(state, b, A, mask)
                 else:
-                    with span("train.step"):
+                    with span("train.dispatch", step=i):
                         state, metrics = jstep(state, b, A)
             else:
-                with span("train.step"):
+                with span("train.dispatch", step=i):
                     state, metrics = jstep(state, b)
             if report is not None:
                 losses.append(metrics["loss"])
@@ -551,8 +552,9 @@ def main(argv=None, report=None) -> int:
                     sum(c.count for c in trace_counters))
             if i % max(1, args.steps // 10) == 0 or i == args.steps - 1:
                 # intentional sync: ~10 progress lines per run
-                print(f"step {i:4d} loss {float(metrics['loss']):.4f} "  # repro-lint: ignore[effect-purity]
-                      f"({time.time()-t0:.1f}s)", flush=True)
+                with span("train.readback", step=i):
+                    loss = float(metrics["loss"])  # repro-lint: ignore[effect-purity]
+                print(f"step {i:4d} loss {loss:.4f} ({time.time()-t0:.1f}s)", flush=True)
     if args.dynamic and controller is not None:
         final = controller.schedule
         desc = (f"randomized schedule {final.name} (C_b="

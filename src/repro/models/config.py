@@ -92,8 +92,8 @@ class ModelConfig:
     n_silos: int = 1
     use_flash_kernel: bool = False         # Pallas path (TPU); jnp ref on CPU
     remat: bool = True
-    # Fully unroll inner attention/mlstm chunk scans so the dry-run's
-    # cost_analysis counts every block (XLA counts a while body once).
+    # Fully unroll inner attention chunk scans so that XLA's
+    # cost_analysis counts every block (it counts a while body once).
     analysis_unroll: bool = False
     # §Perf: banded sliding-window attention (touch only the visible KV
     # band per query block -> O(S*window) instead of O(S^2) masked work).

@@ -25,6 +25,12 @@ Design constraints (enforced by ``tests/test_obs.py`` and the
 * **Thread-local nesting.**  The active span stack is per-thread, so
   concurrent controllers (the multi-tenant direction in ROADMAP.md)
   cannot corrupt each other's parentage.
+* **On the profiler's clock too.**  While enabled, every span also
+  enters a ``jax.profiler.TraceAnnotation`` of its name and attributes,
+  so it lands in any profiler trace being captured, on the clock the
+  device's operations are timed on, beside its ``perf_counter`` record.
+  :func:`enable` imports ``jax.profiler``; importing this module does
+  not.
 
 Aggregation is always on while enabled: finished spans fold into a
 process-local ``{name: (count, total_s, max_s)}`` table read by
@@ -59,11 +65,13 @@ __all__ = [
 
 
 class _State:
-    __slots__ = ("enabled", "capture")
+    __slots__ = ("enabled", "capture", "annotation")
 
     def __init__(self) -> None:
         self.enabled = False
         self.capture = True
+        # jax.profiler.TraceAnnotation, bound by the first enable()
+        self.annotation: Optional[Callable[..., Any]] = None
 
 
 _STATE = _State()
@@ -115,7 +123,7 @@ _NOOP = _NoopSpan()
 class Span:
     """A live (enabled-path) span.  Use via :func:`span`, not directly."""
 
-    __slots__ = ("name", "attrs", "parent", "depth", "_t0")
+    __slots__ = ("name", "attrs", "parent", "depth", "_t0", "_annotation")
 
     def __init__(self, name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -123,6 +131,7 @@ class Span:
         self.parent: Optional[str] = None
         self.depth = 0
         self._t0 = 0.0
+        self._annotation: Any = None
 
     def set(self, **attrs: Any) -> None:
         """Attach attributes to the span (recorded at exit)."""
@@ -135,9 +144,14 @@ class Span:
             self.depth = len(stack)
         stack.append(self)
         self._t0 = time.perf_counter()
+        if _STATE.annotation is not None:  # inside the perf_counter interval
+            self._annotation = _STATE.annotation(self.name, **self.attrs)
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         dur = time.perf_counter() - self._t0
         stack = _stack()
         if stack and stack[-1] is self:
@@ -198,7 +212,12 @@ def span_fn(name: str) -> Callable[[Callable], Callable]:
 
 def enable(capture: bool = True) -> None:
     """Turn span recording on.  ``capture=False`` keeps only the
-    aggregate table (skips the per-span ring — for long runs)."""
+    aggregate table (skips the per-span ring — for long runs).  Spans
+    also go to the profiler's trace from here on."""
+    if _STATE.annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _STATE.annotation = TraceAnnotation
     _STATE.capture = capture
     _STATE.enabled = True
 
