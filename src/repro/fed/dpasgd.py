@@ -261,15 +261,21 @@ def make_train_step(
             )
         else:
             # vmap over the silo dimension: independent local training.
-            def per_silo(p, o, b):
-                p2, o2, _, l = local_sgd_steps(loss_fn, optimizer, p, o, b, step,
+            def per_silo(p, o, b, st):
+                p2, o2, _, l = local_sgd_steps(loss_fn, optimizer, p, o, b, st,
                                                accum_steps=fed.accum_steps,
                                                grad_pspecs=grad_pspecs)
                 return p2, o2, l
 
-            vm = (jax.vmap(per_silo, spmd_axis_name=fed.silo_axis)
-                  if fed.silo_axis else jax.vmap(per_silo))
-            params, opt_state, losses = vm(params, opt_state, batch)
+            vm = jax.vmap(per_silo, in_axes=(0, 0, 0, None))
+            if fed.silo_axis and mesh is not None:
+                # Each device trains its own silos on per-device shapes,
+                # so kernels (Pallas custom calls, which GSPMD cannot
+                # partition) see one silo's arrays.
+                silo = P(fed.silo_axis)
+                vm = jax.shard_map(vm, mesh=mesh, in_specs=(silo, silo, silo, P()),
+                                   out_specs=(silo, silo, silo), check_vma=False)
+            params, opt_state, losses = vm(params, opt_state, batch, step)
             loss = losses.mean()
             # consensus mix (the paper's technique)
             if consensus_arg and fed.gossip_impl != "none":

@@ -13,7 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import flash_attention_pallas
+from .flash_attention import BLOCK_KV, BLOCK_Q, flash_attention_pallas
 from .gossip_mix import gossip_mix_pallas
 from .mlstm_scan import mlstm_scan_pallas
 from .segment_max import edge_segment_max_pallas
@@ -24,18 +24,16 @@ def flash_attention(
     q: jax.Array,  # [B, S, K, G, hd]
     k: jax.Array,
     v: jax.Array,
-    q_pos=None,   # accepted for API parity with the chunked reference
-    kv_pos=None,
     *,
     causal: bool = True,
     window: Optional[int] = None,
-    block_q: int = 128,
-    block_kv: int = 128,
+    block_q: int = BLOCK_Q,
+    block_kv: int = BLOCK_KV,
 ) -> jax.Array:
-    return flash_attention_pallas(
-        q, k, v, causal=causal, window=window,
-        block_q=block_q, block_kv=block_kv,
-    )
+    """Differentiable: the kernel's own backward (bf16 MXU operands)."""
+    return flash_attention_pallas(q, k, v, causal=causal, window=window,
+                                  block_q=block_q, block_kv=block_kv,
+                                  mxu_dtype=jnp.bfloat16)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))

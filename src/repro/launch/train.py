@@ -375,6 +375,13 @@ def main(argv=None, report=None) -> int:
     trace_counters: list = []
     step_fn = make_counted_step(cfg, fed, opt, plan, mesh,
                                 consensus_arg=sched_mode)
+    from repro.models.transformer import attention_impls
+
+    impls = attention_impls(cfg, args.seq_len, jax.default_backend())
+    for impl in ("pallas", "chunked"):
+        obs_metrics.counter(f"attn.{impl}_layers").inc(impls.count(impl))
+    print(f"attention: {impls.count('pallas')} pallas, "
+          f"{impls.count('chunked')} chunked layers", flush=True)
 
     def make_state(key):
         return init_state(cfg, opt, key)

@@ -1,11 +1,15 @@
 """Attention: GQA (causal / sliding-window / bidirectional), DeepSeek MLA
 (multi-head latent attention, absorbed decode path), and cross-attention.
 
-Full-sequence attention uses a memory-bounded chunked (flash-style)
-formulation in pure jnp — `lax.scan` over KV blocks with running
-max/normalizer — so 32k-token prefill lowers without materializing S^2
-score matrices.  The Pallas TPU kernel (repro.kernels.flash_attention)
-implements the same contract and is validated against this reference.
+Full-sequence causal self-attention runs the Pallas flash kernel with
+its own backward (repro.kernels.flash_attention) on a TPU whenever the
+shapes fit it; :func:`attention_impl` makes that choice from the
+backend and the shapes.  Everywhere else (CPU, odd shapes, bidirectional
+encoders, decode, MLA, cross attention) it uses a memory-bounded chunked
+(flash-style) formulation in pure jnp — `lax.scan` over KV blocks with
+running max/normalizer — so 32k-token prefill lowers without
+materializing S^2 score matrices.  The kernel is validated against this
+reference.
 """
 
 from __future__ import annotations
@@ -311,6 +315,26 @@ def naive_attention(q, k, v, q_pos, kv_pos, *, causal, window):
 # GQA block forward
 
 
+def attention_impl(cfg: ModelConfig, S: int, T: int, causal: bool,
+                   window: Optional[int], backend: str) -> str:
+    """``"pallas"`` where :func:`attn_forward`'s self-attention runs the
+    Pallas flash kernel, ``"chunked"`` where it keeps the jnp path: the
+    kernel needs a TPU, causal attention, a head width of whole 128-lane
+    tiles, sequence lengths that are multiples of its blocks, and its
+    resident stripes within the VMEM budget.  ``window`` needs nothing
+    more: the kernel skips the blocks outside the band."""
+    from repro.kernels import flash_attention as fa
+
+    del window
+    hd, G = cfg.head_dim, cfg.n_heads // cfg.n_kv_heads
+    block_q, block_kv = fa.flash_blocks(S, T)
+    fits = (backend == "tpu" and causal and S == T and hd % fa.LANES == 0
+            and block_q % fa.LANES == 0 and block_kv % fa.LANES == 0
+            and S % block_q == 0 and T % block_kv == 0
+            and fa.stripe_bytes(S, T, G, hd) <= fa.STRIPE_BUDGET)
+    return "pallas" if fits else "chunked"
+
+
 def _split_heads(x, n, hd):
     B, S, _ = x.shape
     return x.reshape(B, S, n, hd)
@@ -337,12 +361,13 @@ def attn_forward(
     k = apply_rope(k, cos, sin)
     B, S = x.shape[:2]
     qg = q.reshape(B, S, K, G, hd)
-    use_kernel = cfg.use_flash_kernel and causal
-    if use_kernel:
-        from repro.kernels import ops as kops
+    if attention_impl(cfg, S, S, causal, window, jax.default_backend()) == "pallas":
+        from repro.kernels import flash_attention as fa
 
-        out = kops.flash_attention(qg, k, v, positions, positions,
-                                   causal=causal, window=window)
+        block_q, block_kv = fa.flash_blocks(S, S)
+        out = fa.flash_attention_pallas(qg, k, v, causal=causal, window=window,
+                                        block_q=block_q, block_kv=block_kv,
+                                        mxu_dtype=jnp.bfloat16)
     elif cfg.banded_swa and causal and window is not None and S > 2 * window:
         out = banded_swa_attention(qg, k, v, positions, window=window)
     elif cfg.flash_vjp:

@@ -90,7 +90,9 @@ class ModelConfig:
     norm_eps: float = 1e-5
     # federated / distribution knobs
     n_silos: int = 1
-    use_flash_kernel: bool = False         # Pallas path (TPU); jnp ref on CPU
+    # Pallas mLSTM scan (models/ssm.py) on TPU; attention picks its
+    # kernel from the backend and shapes (attention.attention_impl)
+    use_flash_kernel: bool = False
     remat: bool = True
     # Fully unroll inner attention chunk scans so that XLA's
     # cost_analysis counts every block (it counts a while body once).

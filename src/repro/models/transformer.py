@@ -222,6 +222,22 @@ def forward(
     return logits, aux_total
 
 
+def attention_impls(cfg: ModelConfig, seq_len: int, backend: str) -> List[str]:
+    """:func:`attention.attention_impl` of every self-attention call a
+    :func:`forward` of ``seq_len`` tokens makes on ``backend``: the
+    encoder's layers, then each decoder layer that attends."""
+    impls = []
+    if cfg.is_encdec:
+        T = cfg.encoder.seq_len
+        impls += [A.attention_impl(cfg, T, T, False, None, backend)] * cfg.encoder.n_layers
+    S = seq_len + cfg.vision_prefix_len
+    for layer, kind in enumerate(cfg.block_pattern):
+        if kind in ("attn", "attn_moe", "hymba"):
+            window = cfg.sliding_window if cfg.layer_uses_window(layer) else None
+            impls.append(A.attention_impl(cfg, S, S, True, window, backend))
+    return impls
+
+
 def loss_fn(params, cfg: ModelConfig, batch: Dict[str, jax.Array]) -> jax.Array:
     logits, aux = forward(
         params, cfg, batch["tokens"],

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.gossip_mix import gossip_mix_pallas
@@ -60,6 +61,137 @@ def test_flash_attention_matches_model_chunked_reference():
     b = flash_attention_pallas(q, k, v, causal=True, window=64,
                                block_q=64, block_kv=64, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def _rel_gap(got, want):
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+# bf16 operands round at 2^-8 relative; the gaps of out, dq, dk and dv
+# to the f32 reference stay within a few of those roundings
+MXU_TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+@pytest.mark.parametrize("mxu_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,S,K,G,hd,bq,bkv,window", [
+    (1, 256, 2, 2, 64, 64, 64, None),
+    (2, 256, 1, 1, 32, 128, 64, None),
+    (1, 256, 1, 2, 32, 64, 128, None),
+    (1, 256, 1, 2, 128, 128, 128, None),
+    (1, 512, 1, 2, 32, 128, 128, 128),    # window of one block: 2 of 4 skipped
+    (1, 512, 2, 1, 32, 128, 128, 128),
+    (1, 256, 1, 2, 32, 64, 64, 100),      # band edge inside a block
+    (1, 256, 1, 1, 32, 64, 32, 300),      # window longer than the sequence
+])
+def test_flash_attention_vjp_matches_reference(B, S, K, G, hd, bq, bkv, window,
+                                               mxu_dtype):
+    """Forward against naive attention, dq/dk/dv against autodiff of the
+    model's chunked attention, both in f32; skipped blocks leave out only
+    work of weight zero, so the windowed cases match as closely."""
+    from repro.models.attention import chunked_attention, naive_attention
+
+    q, k, v = _attn_inputs(jax.random.PRNGKey(S + bq + G), B, S, K, G, hd,
+                           jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), q.shape)
+    pos = jnp.arange(S, dtype=jnp.int32)
+
+    def kernel(q, k, v):
+        return flash_attention_pallas(q, k, v, causal=True, window=window,
+                                      block_q=bq, block_kv=bkv,
+                                      mxu_dtype=mxu_dtype, interpret=True)
+
+    def chunked(q, k, v):
+        return chunked_attention(q, k, v, pos, pos, causal=True, window=window,
+                                 kv_block=64)
+
+    def grads(attn):
+        return jax.grad(lambda q, k, v: jnp.sum(attn(q, k, v) * w),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    with jax.default_matmul_precision("highest"):
+        want = naive_attention(q, k, v, pos, pos, causal=True, window=window)
+        want_grads = grads(chunked)
+    tol = MXU_TOL[mxu_dtype]
+    assert _rel_gap(kernel(q, k, v), want) < tol
+    for got, expect in zip(grads(kernel), want_grads):
+        assert _rel_gap(got, expect) < tol
+
+
+def _pairs_seen(q_blk, k_blk, bq, bkv, window):
+    """How many of a tile's (query, key) pairs causal attention sees."""
+    qp = np.arange(q_blk * bq, (q_blk + 1) * bq)[:, None]
+    kp = np.arange(k_blk * bkv, (k_blk + 1) * bkv)[None, :]
+    seen = kp <= qp
+    if window is not None:
+        seen &= qp - kp < window
+    return int(seen.sum())
+
+
+def _visits(spans):
+    masked, plain = [], []
+    for start, stop, is_masked in spans:
+        (masked if is_masked else plain).extend(range(int(start), int(stop)))
+    return masked, plain
+
+
+@pytest.mark.parametrize("S,bq,bkv,window", [
+    (1024, 128, 128, None), (1024, 128, 256, None), (1024, 256, 128, None),
+    (1024, 128, 128, 128), (1024, 256, 128, 100), (1024, 128, 256, 300),
+    (1024, 128, 128, 2000),
+])
+def test_flash_attention_visits_exactly_the_visible_blocks(S, bq, bkv, window):
+    """Each loop visits every block holding a pair attention sees, once;
+    only blocks the diagonal or the band's edge cuts are masked."""
+    n_q, n_kv = S // bq, S // bkv
+    full = bq * bkv
+    for i in range(n_q):
+        masked, plain = _visits(fa._kv_spans(i, bq, bkv, n_kv, True, window))
+        seen = [_pairs_seen(i, j, bq, bkv, window) for j in range(n_kv)]
+        assert sorted(masked + plain) == [j for j in range(n_kv) if seen[j]]
+        assert plain == [j for j in range(n_kv) if seen[j] == full]
+    for j in range(n_kv):
+        masked, plain = _visits(fa._q_spans(j, bq, bkv, n_q, True, window))
+        seen = [_pairs_seen(i, j, bq, bkv, window) for i in range(n_q)]
+        assert sorted(masked + plain) == [i for i in range(n_q) if seen[i]]
+        assert plain == [i for i in range(n_q) if seen[i] == full]
+
+
+@pytest.mark.parametrize("S,causal,head_dim,backend,expect", [
+    (2048, True, 128, "tpu", "pallas"),   # the benchmark cells' attention
+    (4096, True, 128, "tpu", "pallas"),
+    (2048, True, 128, "cpu", "chunked"),
+    (2048, False, 128, "tpu", "chunked"),  # bidirectional encoder
+    (2048, True, 64, "tpu", "chunked"),
+    (2000, True, 128, "tpu", "chunked"),   # not a multiple of the block
+    (32768, True, 128, "tpu", "chunked"),  # stripes beyond the VMEM budget
+])
+def test_attention_impl_picks_the_kernel_where_it_fits(S, causal, head_dim,
+                                                       backend, expect):
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models.attention import attention_impl
+
+    cfg = get_config("internlm2-1.8b")
+    cfg = dataclasses.replace(cfg, head_dim=head_dim,
+                              d_model=head_dim * cfg.n_heads)
+    assert attention_impl(cfg, S, S, causal, None, backend) == expect
+    assert attention_impl(cfg, S, S, causal, 512, backend) == expect
+
+
+def test_attention_impls_counts_the_cells_layers():
+    """The four attention layers of the benchmark's internlm2 take the
+    kernel on a TPU and the jnp path elsewhere; an encoder's layers
+    always keep the jnp path."""
+    from repro.configs import get_config
+    from repro.models.transformer import attention_impls
+
+    cfg = get_config("internlm2-1.8b", n_layers=4)
+    assert attention_impls(cfg, 2048, "tpu") == ["pallas"] * 4
+    assert attention_impls(cfg, 2048, "cpu") == ["chunked"] * 4
+    whisper = get_config("whisper-large-v3")
+    impls = attention_impls(whisper, 2048, "tpu")
+    assert impls[:whisper.encoder.n_layers] == ["chunked"] * whisper.encoder.n_layers
 
 
 @settings(max_examples=12, deadline=None)

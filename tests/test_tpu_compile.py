@@ -10,6 +10,8 @@ never at import: only one process may load the TPU library, and pytest
 workers import every test file.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -24,7 +26,7 @@ from repro.kernels.segment_max import edge_segment_max_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import os
 
     from jax.experimental import topologies
@@ -41,9 +43,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, *shapes, sharding):
@@ -74,6 +81,94 @@ def test_flash_attention_compiles_at_internlm2_heads(one_chip):
     _compile(lambda q, k, v: flash_attention_pallas(q, k, v, interpret=False),
              ((1, S, K, G, hd), jnp.bfloat16), ((1, S, K, hd), jnp.bfloat16),
              ((1, S, K, hd), jnp.bfloat16), sharding=one_chip)
+
+
+def test_flash_attention_grad_compiles_at_internlm2_heads(one_chip):
+    """The kernel's forward and both backward kernels at the benchmark
+    cells' shapes: batch 4, 2048 tokens, f32 activations."""
+    cfg = get_config("internlm2-1.8b")
+    B, S = 4, 2048
+    K, G, hd = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_pallas(q, k, v, block_q=512, block_kv=512,
+                                              mxu_dtype=jnp.bfloat16,
+                                              interpret=False))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        *[jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+          for s in ((B, S, K, G, hd), (B, S, K, hd), (B, S, K, hd))]
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def _on_tpu(monkeypatch):
+    """Let the program's backend checks see the described chip: the
+    process itself runs on the CPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _state_shapes(cfg, opt, sharding_of):
+    from repro.fed import init_state
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding_of(x)),
+        jax.eval_shape(lambda k: init_state(cfg, opt, k), jax.random.PRNGKey(0)))
+
+
+def test_one_silo_step_compiles_with_flash_attention(one_chip, monkeypatch):
+    """The benchmark's one-silo round (batch 4 x 2048 tokens, one local
+    step) with the kernel on its path: each layer's attention is the
+    kernel's forward, its remat recompute and its two backward kernels,
+    and no buffer holds the jnp path's f32 score blocks."""
+    from repro.fed import DPASGDConfig, make_train_step
+    from repro.optim import momentum
+
+    _on_tpu(monkeypatch)
+    cfg = get_config("internlm2-1.8b", n_layers=1)
+    opt = momentum(0.05, 0.9)
+    step = make_train_step(cfg, DPASGDConfig(local_steps=1, gossip_impl="none"),
+                           opt, None)
+    tokens = jax.ShapeDtypeStruct((1, 4, 2048), jnp.int32, sharding=one_chip)
+    text = jax.jit(step, donate_argnums=0).lower(
+        _state_shapes(cfg, opt, lambda x: one_chip),
+        {"tokens": tokens, "labels": tokens}).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert re.search(r"f32\[[\d,]*2048,8,2,1024\]", text) is None
+
+
+def test_ring4_step_compiles_with_per_chip_flash_attention(topo, monkeypatch):
+    """Four silos on four described chips, the ring's one ppermute: each
+    chip runs the kernels on its own silo's shapes (no all-gather), and
+    the only collectives are the exchange of the parameters and the
+    loss's reduction."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.fed import DPASGDConfig, make_train_step
+    from repro.launch.hlo_analysis import collective_bytes
+    from repro.optim import momentum
+
+    _on_tpu(monkeypatch)
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",), axis_types=(AxisType.Auto,))
+    cfg = get_config("internlm2-1.8b", n_layers=1, n_silos=4)
+    opt = momentum(0.05, 0.9)
+    step = make_train_step(
+        cfg, DPASGDConfig(local_steps=1, gossip_impl="ppermute", silo_axis="data"),
+        opt, plan_for_n_silos("ring", 4), mesh)
+    silo = lambda x: NamedSharding(mesh, P("data") if x.ndim else P())  # noqa: E731
+    state = _state_shapes(cfg, opt, silo)
+    tokens = jax.ShapeDtypeStruct((4, 1, 4, 2048), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    text = jax.jit(step, donate_argnums=0).lower(
+        state, {"tokens": tokens, "labels": tokens}).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert "all-gather" not in text
+    param_bytes = sum(x.size // 4 * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(state["params"]))
+    got = collective_bytes(text)
+    assert got["collective-permute"] == param_bytes
+    assert got["all-reduce"] == 4
 
 
 def test_mlstm_scan_compiles_at_xlstm_heads(one_chip):
