@@ -13,20 +13,21 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import importlib
 import json
 import shutil
 import tempfile
 import time
 from pathlib import Path
-from typing import Dict, List
+from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from chipbench import compare, flops, hlo_bytes, trace, weights
+from chipbench import compare, flops, hlo_bytes, scopes, trace, weights
 from chipbench import reference as references
 from chipbench.reference import rounds as reference_rounds
 
@@ -57,11 +58,15 @@ def load_cell(root: Path, name: str) -> Cell:
         raise KeyError(f"no workload {name!r}; known: {sorted(cells)}")
     w = cells[name]
     config = next(c for c in bench["configs"] if c["name"] == w["config"])
+    cfg = json.loads((root / config["file"]).read_text())
+    refused = reduced_refusals(cfg)
+    if refused:
+        raise ValueError(f"{config['file']}: " + "; ".join(refused))
     has = lambda m: name in m.get("workloads", [name])  # noqa: E731
     return Cell(
         name=name,
         chips=w["chips"],
-        config=json.loads((root / config["file"]).read_text()),
+        config=cfg,
         traffic=json.loads((root / "chipbench" / "traffic" / f"{w['traffic']}.json").read_text()),
         limits=compare.load_limits(root, name),
         end_to_end=[m for m in bench["end_to_end"] if has(m)],
@@ -94,9 +99,80 @@ class CompileClock:
         jax.monitoring.unregister_event_listener(self._event)
 
 
+# The keys ``reduced`` may cut: the depth, and one chip's share of a
+# layer that the deployment divides over ``share`` chips: the
+# vocabulary, and the routed experts under the names the published
+# configs give their count.  Every other key, every width among them,
+# is as published.
+DEPTH_KEY = "num_hidden_layers"
+SHARE_KEYS = ("vocab_size", "n_routed_experts", "num_local_experts", "num_experts")
+
+
+def share_floor(key: str, published: int) -> int:
+    """The least share of ``key`` a file keeps: an eighth of the
+    vocabulary, 8 routed experts."""
+    return -(-published // 8) if key == "vocab_size" else 8
+
+
+def reduced_refusals(cfg: dict) -> List[str]:
+    """Why the configuration file's ``reduced`` cuts are refused, one
+    line per key; empty where all hold.  A cut changes its key from the
+    published value and is the depth or a chip's share; a share states
+    ``share``, the chips that share the layer, holds the published count
+    over ``share`` (rounded up), and keeps the floor."""
+    out = []
+    for key, entry in cfg["reduced"].items():
+        published, share = entry["published"], entry.get("share")
+        if cfg.get(key) == published:
+            out.append(f"{key}: {published} is the published value")
+        elif key == DEPTH_KEY:
+            continue
+        elif key not in SHARE_KEYS:
+            out.append(f"{key}: only the depth and a chip's share of the vocabulary or "
+                       f"the routed experts are cut")
+        elif not isinstance(share, int) or share < 2:
+            out.append(f"{key}: a chip's share states 'share', the chips that share the layer")
+        elif cfg[key] != -(-published // share):
+            out.append(f"{key}: {cfg[key]} is not {published} over {share} chips")
+        elif cfg[key] < share_floor(key, published):
+            out.append(f"{key}: {cfg[key]} is under the floor {share_floor(key, published)}")
+    return out
+
+
+def share_fields(cfg: dict) -> List[Tuple[str, str]]:
+    """``(key, program field)`` for each share of the routed experts the
+    file's ``reduced`` lists: the field, dotted (``moe.<name>``), that
+    holds the experts one chip computes, from the ``SHARE_FIELDS`` table
+    (published key -> field) of the family's reference module.  The
+    vocabulary's share is the program's ``vocab_size``, set with the
+    other sizes."""
+    keys = [k for k in cfg.get("reduced", {}) if k in SHARE_KEYS and k != "vocab_size"]
+    table = getattr(references.model(cfg["reference"]), "SHARE_FIELDS", {}) if keys else {}
+    missing = [k for k in keys if k not in table]
+    if missing:
+        raise ValueError(f"{missing}: reference/{cfg['reference']}.py names no program field "
+                         f"that holds a chip's share (SHARE_FIELDS)")
+    return [(k, table[k]) for k in keys]
+
+
+def _with_field(obj, dotted: str, value):
+    """``obj`` with the field ``dotted`` (``moe.n_shared``) set."""
+    def put(o, names):
+        if not (dataclasses.is_dataclass(o)
+                and names[0] in {f.name for f in dataclasses.fields(o)}):
+            raise ValueError(f"the program has no field {dotted!r}")
+        v = put(getattr(o, names[0]), names[1:]) if names[1:] else value
+        return dataclasses.replace(o, **{names[0]: v})
+
+    return put(obj, dotted.split("."))
+
+
 def program_config(cfg: dict, n_silos: int):
     """The program's ``ModelConfig`` for a configuration file: the
-    registry entry of its ``arch_id`` with the file's sizes."""
+    registry entry of its ``arch_id`` with the file's sizes, and its
+    shares of the routed experts in the fields ``share_fields`` names.
+    A field that holds the published count is the router's width, and
+    is refused: the share is the experts one chip computes."""
     from repro.configs import get_config
 
     base = get_config(cfg["arch_id"])
@@ -119,7 +195,14 @@ def program_config(cfg: dict, n_silos: int):
         sizes["rope_theta"] = cfg["rope_theta"]
     if "mlstm_proj_factor" in cfg:
         sizes["ssm"] = dataclasses.replace(base.ssm, expand=cfg["mlstm_proj_factor"])
-    return dataclasses.replace(base, **sizes)
+    out = dataclasses.replace(base, **sizes)
+    for key, field in share_fields(cfg):
+        new, published = _with_field(out, field, cfg[key]), cfg["reduced"][key]["published"]
+        if functools.reduce(getattr, field.split("."), out) == published:
+            raise ValueError(f"{key}: {field} holds the published {published}, the router's "
+                             f"width; a share goes to the field of the experts one chip computes")
+        out = new
+    return out
 
 
 def _annotate(on: bool):
@@ -323,9 +406,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, devices,
         log(f"compiled step: arguments {mem.argument_size_in_bytes} B, temps "
             f"{mem.temp_size_in_bytes} B, outputs {mem.output_size_in_bytes} B, "
             f"aliased {mem.alias_size_in_bytes} B")
-        collectives = hlo_bytes.collective_bytes(compiled.as_text())
+        text = compiled.as_text()
+        collectives = hlo_bytes.collective_bytes(text)
         log(f"compiled step collectives: {collectives}")
-        del compiled
+        names = scopes.op_names(text) if traced else {}
+        del compiled, text
         compiles_setup, hits_setup = clock.compiles, clock.cache_hits
         log(f"set-up: {clock.compiles} compiles, {clock.seconds:.3f} s compiling, "
             f"{clock.cache_hits} persistent-cache hits")
@@ -333,6 +418,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, devices,
         tdir = tempfile.mkdtemp(prefix="chipbench-trace-") if traced else None
         setup_s = time.time() - t_start
         if traced:
+            import repro.obs as obs
+
+            # the program's spans land in the trace while repro.obs is on
+            obs.enable(capture=False)
             jax.profiler.start_trace(tdir, profiler_options=_profile_options())
         try:
             with _annotate(traced)("window"):
@@ -341,6 +430,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, devices,
         finally:
             if traced:
                 jax.profiler.stop_trace()
+                obs.disable()
         in_window = clock.compiles - compiles_setup
         log(f"window: {n_rounds} rounds in {elapsed:.6f} s, {in_window} compiles "
             f"({clock.cache_hits - hits_setup} cache hits) inside it")
@@ -368,7 +458,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, devices,
         facts = trace.Facts(trace=tr, rounds=n_rounds, chips=len(devices), peak=peak,
                             flops_per_round=tokens_per_round(cell) * flops.train_flops_per_token(
                                 cell.config, cell.traffic["seq_len"]),
-                            collective_bytes=collectives)
+                            collective_bytes=collectives, op_names=names)
+        _log_scopes(facts, log)
         metrics = {}
         for m in cell.per_layer:
             value = importlib.import_module(f"chipbench.metrics.{m['name']}").read(facts)
@@ -400,6 +491,23 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, devices,
         result["breakdown"] = breakdown
     result["checks"] = checks
     return result
+
+
+def _log_scopes(facts: trace.Facts, log) -> None:
+    """The traced round by the program's scopes against busy time, the
+    operations with most time outside the named scopes with their
+    ``op_name``, and the dispatch lead."""
+    tr, rounds = facts.trace, max(facts.rounds, 1)
+    by_scope = scopes.device_ms(tr, facts.op_names, rounds)
+    log(f"device ms per round by scope: {by_scope}; sum {sum(by_scope.values()):.6f}, "
+        f"busy {tr.busy_s() / rounds * 1e3:.6f}")
+    n_ops = sum(len(ops) for ops in tr.ops.values())
+    other = [[n, t / rounds * 1e3, facts.op_names.get(n)] for n, t in tr.top_ops(n_ops)
+             if scopes.scope_of(facts.op_names.get(n, "")) == "other"][:10]
+    log(f"device ms per round outside the named scopes, top ops: {other}")
+    log(f"dispatch lead: {tr.dispatch_lead_ms()} ms; step executions per device "
+        f"{ {d: len(m) for d, m in tr.modules.items()} }, dispatches "
+        f"{len(tr.host_spans('dispatch'))}")
 
 
 def tokens_per_round(cell: Cell) -> int:

@@ -8,6 +8,9 @@ import importlib
 
 
 def model(name: str):
-    """The reference module ``name``: ``layout(cfg)`` and
-    ``loss(params, cfg, tokens, labels)``."""
+    """The reference module ``name``: ``layout(cfg)``, ``loss(params,
+    cfg, tokens, labels)`` and ``layer_flops(cfg, kind, S)``, the
+    forward FLOPs of one layer of each block kind the family has; and,
+    for a family whose files cut a chip's share of the routed experts,
+    ``SHARE_FIELDS`` (``harness.share_fields``)."""
     return importlib.import_module(f"chipbench.reference.{name}")

@@ -40,6 +40,22 @@ def layout(cfg):
     return lm_layout(cfg, block)
 
 
+def layer_flops(cfg, kind, S):
+    """Forward FLOPs of one ``attn`` layer over a sequence of ``S``: every
+    matmul from its shapes; a causal query sees (S + 1) / 2 keys on
+    average."""
+    if kind != "attn":
+        raise KeyError(f"no FLOP count for block kind {kind!r}")
+    D, H, K, hd = (cfg["hidden_size"], cfg["num_attention_heads"],
+                   cfg["num_key_value_heads"], cfg["head_dim"])
+    Te = (S + 1) / 2
+    f = 2 * S * D * (H + 2 * K) * hd          # q, k, v projections
+    f += 4 * S * Te * H * hd                   # q k^T and p v
+    f += 2 * S * H * hd * D                    # output projection
+    f += 6 * S * D * cfg["intermediate_size"]  # SwiGLU
+    return f
+
+
 def rope(x, theta):
     """Rotary embedding of x [B, S, heads, hd], rotate-half convention."""
     S, hd = x.shape[1], x.shape[-1]

@@ -67,6 +67,28 @@ def layout(cfg):
     return lm_layout(cfg, block)
 
 
+def layer_flops(cfg, kind, S):
+    """Forward FLOPs of one ``mlstm`` or ``slstm`` layer over a sequence
+    of ``S``, as the program computes it: the mLSTM in chunks of
+    ``mlstm_chunk``, every matmul from its shapes."""
+    D, H = cfg["hidden_size"], cfg["num_heads"]
+    if kind == "mlstm":
+        Di = cfg["mlstm_proj_factor"] * D
+        hdi = Di // H
+        C = min(cfg["mlstm_chunk"], S)
+        f = 2 * S * D * 2 * Di                     # up-projection, x and gate
+        f += 3 * 2 * S * Di * Di                   # q, k, v
+        f += H * (4 * S * C * hdi + 4 * S * hdi * hdi)  # chunked scan
+        f += 2 * S * Di * D                        # down-projection
+        return f
+    if kind == "slstm":
+        dh = D // H
+        f = 2 * S * D * 4 * D + 8 * S * D * dh     # gate pre-activations, recurrence
+        f += 2 * S * D * D                         # down-projection
+        return f
+    raise KeyError(f"no FLOP count for block kind {kind!r}")
+
+
 def _segment_log_decay(log_f):
     """L[b, h, t, s] = sum_{s<r<=t} log_f[b, r, h] for s <= t.
 

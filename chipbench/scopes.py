@@ -4,27 +4,23 @@ that read it.
 - **Scopes.**  ``fed/dpasgd.py`` and ``fed/gossip.py`` run the round's
   work under ``jax.named_scope``s, which the compiled HLO keeps in each
   instruction's ``op_name`` metadata; the trace names each device
-  operation by its instruction alone.  :func:`of_hlo` maps instruction
-  names to ``forward``, ``backward``, ``optimizer``, ``gossip`` or
-  ``other`` from the compiled step's text, and :func:`device_ms` sums
+  operation by its instruction alone.  :func:`op_names` maps instruction
+  names to their ``op_name`` from the compiled step's text,
+  :func:`scope_of` an ``op_name`` to ``forward``, ``backward``,
+  ``optimizer``, ``gossip`` or ``other``, and :func:`device_ms` sums
   device self time per round by scope.
+  :func:`named_ms` reads any scope by name, so that the reader of a
+  new ``jax.named_scope`` is one call.
 - **Program spans.**  ``repro.obs`` spans, while enabled, are also
-  profiler annotations on the device trace's clock; :func:`load` keeps
-  those named in ``PROGRAM_SPANS`` beside the benchmark's own spans,
-  and each device's ``XLA Modules`` events, one per executed step.
-- **Clock.**  :meth:`ProgramTrace.dispatch_lead_ms`: how long after the
-  host's ``dispatch`` span opens the step starts on the device.
+  profiler annotations on the device trace's clock; ``trace.load``
+  keeps those named in ``PROGRAM_SPANS`` beside the benchmark's own.
 """
 
 from __future__ import annotations
 
-import collections
 import dataclasses
 import re
-from pathlib import Path
-from typing import Dict, List, Optional, Sequence
-
-from chipbench import trace
+from typing import Dict, List, Optional
 
 SCOPES = ("forward", "backward", "optimizer", "gossip", "other")
 PROGRAM_SPANS = ("input.batch",)
@@ -34,7 +30,6 @@ _INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*.*?\s([\w\-]+)\("
 _CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 _OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
 _MATMUL = ("dot", "convolution")
-_DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 
 
 def scope_of(op_name: str) -> str:
@@ -119,97 +114,52 @@ def op_names(text: str) -> Dict[str, str]:
             for name, ins in instructions.items()}
 
 
-def of_hlo(text: str) -> Dict[str, str]:
-    """``{instruction name: scope}`` (``SCOPES``) for every instruction
-    of an HLO module's text, from :func:`op_names`."""
-    return {name: scope_of(op) for name, op in op_names(text).items()}
-
-
-def device_ms(tr: trace.Trace, op_scopes: Dict[str, str], rounds: int) -> Dict[str, float]:
-    """Device self time per round and chip by scope, mean over the
-    chips, in milliseconds; operations the step's text does not name
-    count as ``other``.  The scopes add up to the busy time per round."""
+def device_ms(tr, names: Dict[str, str], rounds: int) -> Dict[str, float]:
+    """Device self time per round and chip by scope (``SCOPES``) of the
+    trace ``tr`` (``trace.Trace``), mean over the chips, in
+    milliseconds; ``names`` is :func:`op_names` of the step, and an
+    operation it does not name counts as ``other``.  The scopes add up
+    to the busy time per round."""
     total = dict.fromkeys(SCOPES, 0.0)
-    n_ops = sum(len(ops) for ops in tr.ops.values())
-    for name, seconds in tr.top_ops(n_ops):
-        total[op_scopes.get(name, "other")] += seconds
+    for name, seconds in _all_ops(tr):
+        total[scope_of(names.get(name, ""))] += seconds
     return {k: v / rounds * 1e3 for k, v in total.items()}
 
 
 def read_ms(facts, scope: str) -> Optional[float]:
-    """A per-layer metric's reading of ``scope``, or ``None`` when the
-    facts carry no op scopes or the step has no operation in it."""
-    op_scopes = getattr(facts, "op_scopes", None)
-    if not op_scopes or scope not in op_scopes.values() or facts.rounds == 0:
+    """A per-layer metric's reading of ``scope`` from ``facts``
+    (``trace.Facts``), or ``None`` when the facts carry no op names or
+    the step has no operation in it."""
+    if facts.rounds == 0 or scope not in {scope_of(op) for op in facts.op_names.values()}:
         return None
-    return device_ms(facts.trace, op_scopes, facts.rounds)[scope]
+    return device_ms(facts.trace, facts.op_names, facts.rounds)[scope]
 
 
-@dataclasses.dataclass
-class ScopedFacts(trace.Facts):
-    """``trace.Facts`` with the compiled step's ``{op: scope}``."""
-    op_scopes: Dict[str, str] = dataclasses.field(default_factory=dict)
+def named_ms(facts, name: str) -> Optional[float]:
+    """Device self time per round and chip, mean over the chips, in
+    milliseconds, of the operations whose name stack holds the component
+    ``name`` (a ``jax.named_scope`` of the program), in forward and
+    backward alike: ``moe_dispatch`` is found in
+    ``jvp(forward)/moe_dispatch`` and in
+    ``transpose(jvp(forward))/moe_dispatch``, and ``forward`` in both.
+    ``None`` where the step has no such operation."""
+    inside = {n for n, op in facts.op_names.items() if name in _components(op)}
+    if facts.rounds == 0 or not inside:
+        return None
+    return sum(t for n, t in _all_ops(facts.trace) if n in inside) / facts.rounds * 1e3
 
 
-@dataclasses.dataclass
-class ProgramTrace(trace.Trace):
-    """A ``trace.Trace`` whose spans include the program's own, with
-    each device's executed steps (``XLA Modules`` events)."""
-    modules: Dict[int, List[trace.Interval]] = dataclasses.field(default_factory=dict)
-
-    def dispatch_lead_ms(self) -> Optional[float]:
-        """The least, over the window's rounds and the devices, of a
-        step's start on the device less the start of the ``dispatch``
-        span that sent it, in milliseconds.  Negative: the two clocks
-        disagree by at least that much.  A device whose trace holds
-        another number of step executions than the window has rounds
-        cannot be paired and is left out."""
-        sent = self.host_spans("dispatch")
-        leads = [(m[0] - d[0]) * 1e-6 for mods in self.modules.values()
-                 if len(mods) == len(sent) for m, d in zip(mods, sent)]
-        return min(leads) if leads else None
+_TRANSFORM = re.compile(r"^(?:[\w\-]+\()+([^()]*)\)+$")
 
 
-def _newest(directory) -> Path:
-    files = sorted(Path(directory).rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
-    if not files:
-        raise FileNotFoundError(f"no *.xplane.pb under {directory}")
-    return files[-1]
+def _components(op_name: str) -> List[str]:
+    """The name-stack components of an ``op_name``'s first ``;``-joined
+    entry, each out of its transforms: ``transpose(jvp(forward))`` is
+    ``forward``."""
+    parts = op_name.split(";", 1)[0].split("/")
+    return [m.group(1) if (m := _TRANSFORM.match(p)) else p for p in parts]
 
 
-def program_spans(directory) -> List[trace.Interval]:
-    """(start, end, name) of the host events named in ``PROGRAM_SPANS``
-    in the newest trace under ``directory``."""
-    import jax
-
-    data = jax.profiler.ProfileData.from_file(str(_newest(directory)))
-    return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
-            for plane in data.planes if plane.name.startswith("/host:")
-            for line in plane.lines for e in line.events if e.name in PROGRAM_SPANS]
-
-
-def modules(directory, device_ids: Optional[Sequence[int]] = None) -> Dict[int, List[trace.Interval]]:
-    """Per TPU device, the (start, end) of each execution of its most
-    frequent ``XLA Modules`` module (the step), in time order."""
-    import jax
-
-    data = jax.profiler.ProfileData.from_file(str(_newest(directory)))
-    out = {}
-    for plane in data.planes:
-        m = _DEVICE_PLANE.match(plane.name)
-        if not m or (device_ids is not None and int(m.group(1)) not in device_ids):
-            continue
-        for line in plane.lines:
-            if line.name == "XLA Modules":
-                events = [(e.start_ns, e.start_ns + e.duration_ns, e.name) for e in line.events]
-                if events:
-                    step = collections.Counter(n for _, _, n in events).most_common(1)[0][0]
-                    out[int(m.group(1))] = sorted((s, e) for s, e, n in events if n == step)
-    return out
-
-
-def load(directory, device_ids: Optional[Sequence[int]] = None) -> ProgramTrace:
-    """``trace.load``, with the program's spans and the executed steps."""
-    tr = trace.load(directory, device_ids)
-    return ProgramTrace(ops=tr.ops, spans=tr.spans + program_spans(directory),
-                        window=tr.window, modules=modules(directory, device_ids))
+def _all_ops(tr):
+    """(name, seconds per chip) of every operation of the trace."""
+    return tr.top_ops(sum(len(ops) for ops in tr.ops.values()))

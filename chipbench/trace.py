@@ -1,20 +1,26 @@
 """From a profiler trace (``*.xplane.pb``) to per-device operation
-intervals and the benchmark's own host spans, and the reductions the
+intervals, executed steps and host spans, and the reductions the
 per-layer metrics share: busy time, idle gaps named by the host span
-open in them, and collective time that no other operation hides.
+open in them, collective time that no other operation hides, and how
+long after its dispatch a step starts on the device.
 
-Device operations are the events of each TPU plane's ``XLA Ops`` line;
-host spans are the ``TraceAnnotation`` events the harness writes
-(``window``, ``input``, ``dispatch``, ``readback``).  Times are in
-nanoseconds on the profiler's clock, which host and device share.
+Device operations are the events of each TPU plane's ``XLA Ops`` line,
+executed steps those of its ``XLA Modules`` line.  Host spans are the
+``TraceAnnotation`` events the harness writes (``window``, ``input``,
+``dispatch``, ``readback``) and the program's own ``repro.obs`` spans
+named in ``scopes.PROGRAM_SPANS``.  Times are in nanoseconds on the
+profiler's clock, which host and device share.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import re
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
+
+from chipbench import scopes
 
 Interval = Tuple[float, float]
 HOST_SPANS = ("window", "input", "dispatch", "readback")
@@ -58,6 +64,8 @@ class Trace:
     ops: Dict[int, List[Tuple[float, float, str]]]   # device id -> (start, end, name)
     spans: List[Tuple[float, float, str]]            # host spans
     window: Interval
+    # device id -> (start, end) of each execution of the step, in order
+    modules: Dict[int, List[Interval]] = dataclasses.field(default_factory=dict)
 
     def device_ops(self, dev: int) -> List[Tuple[float, float, str]]:
         lo, hi = self.window
@@ -114,6 +122,18 @@ class Trace:
             named.append([best if cover[best] > 0 else "none", (g[1] - g[0]) * 1e-9])
         return named
 
+    def dispatch_lead_ms(self) -> Optional[float]:
+        """The least, over the window's rounds and the devices, of a
+        step's start on the device less the start of the ``dispatch``
+        span that sent it, in milliseconds.  Negative: the two clocks
+        disagree by at least that much.  A device whose trace holds
+        another number of step executions than the window has rounds
+        cannot be paired and is left out."""
+        sent = self.host_spans("dispatch")
+        leads = [(m[0] - d[0]) * 1e-6 for mods in self.modules.values()
+                 if len(mods) == len(sent) for m, d in zip(mods, sent)]
+        return min(leads) if leads else None
+
 
 def _self_times(ops: Sequence[Tuple[float, float, str]]):
     """(name, self time) of possibly nested events on one line."""
@@ -135,45 +155,71 @@ def op_name(text: str) -> str:
     return text.split(" = ", 1)[0].lstrip("%")
 
 
-def load(directory, device_ids: Optional[Sequence[int]] = None) -> Trace:
-    """The trace under ``directory`` (the newest ``*.xplane.pb``)."""
+def profile(directory):
+    """The newest ``*.xplane.pb`` under ``directory``, read."""
     import jax
 
     files = sorted(Path(directory).rglob("*.xplane.pb"), key=lambda p: p.stat().st_mtime)
     if not files:
         raise FileNotFoundError(f"no *.xplane.pb under {directory}")
-    data = jax.profiler.ProfileData.from_file(str(files[-1]))
+    return jax.profiler.ProfileData.from_file(str(files[-1]))
+
+
+def host_events(data) -> List[Tuple[float, float, str]]:
+    """(start, end, name) of the host spans of a read trace that the
+    harness or the program writes (``HOST_SPANS``,
+    ``scopes.PROGRAM_SPANS``)."""
+    names = set(HOST_SPANS) | set(scopes.PROGRAM_SPANS)
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events if e.name in names]
+
+
+def _steps(events) -> List[Interval]:
+    """(start, end) of each execution of the most frequent module of an
+    ``XLA Modules`` line, the step, in time order."""
+    if not events:
+        return []
+    step = collections.Counter(e.name for e in events).most_common(1)[0][0]
+    return sorted((e.start_ns, e.start_ns + e.duration_ns) for e in events if e.name == step)
+
+
+def load(directory, device_ids: Optional[Sequence[int]] = None) -> Trace:
+    """The trace under ``directory`` (the newest ``*.xplane.pb``)."""
+    data = profile(directory)
     ops: Dict[int, list] = {}
-    spans = []
+    modules: Dict[int, List[Interval]] = {}
     for plane in data.planes:
         m = _DEVICE_PLANE.match(plane.name)
-        if m:
-            dev = int(m.group(1))
-            if device_ids is not None and dev not in device_ids:
-                continue
-            for line in plane.lines:
-                if line.name == "XLA Ops":
-                    ops[dev] = [(e.start_ns, e.start_ns + e.duration_ns, op_name(e.name))
-                                for e in line.events]
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                spans += [(e.start_ns, e.start_ns + e.duration_ns, e.name)
-                          for e in line.events if e.name in HOST_SPANS]
+        if not m or (device_ids is not None and int(m.group(1)) not in device_ids):
+            continue
+        dev = int(m.group(1))
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                ops[dev] = [(e.start_ns, e.start_ns + e.duration_ns, op_name(e.name))
+                            for e in line.events]
+            elif line.name == "XLA Modules":
+                modules[dev] = _steps(list(line.events))
     if not ops:
-        raise ValueError(f"no device operations in {files[-1]}")
+        raise ValueError(f"no device operations in the trace under {directory}")
+    spans = host_events(data)
     windows = [(s, e) for s, e, n in spans if n == "window"]
     if not windows:
-        raise ValueError(f"no 'window' span in {files[-1]}")
-    return Trace(ops=ops, spans=spans, window=windows[0])
+        raise ValueError(f"no 'window' span in the trace under {directory}")
+    return Trace(ops=ops, spans=spans, window=windows[0],
+                 modules={d: v for d, v in modules.items() if v})
 
 
 @dataclasses.dataclass
 class Facts:
     """What a per-layer metric reads: the traced window, the rounds run
-    in it, the devices' peaks, and counts from the compiled step."""
+    in it, the devices' peaks, and from the compiled step its
+    collective bytes and, for each instruction, the ``op_name`` its
+    scope is read from (``scopes.op_names``)."""
     trace: Trace
     rounds: int
     chips: int
     peak: object
     flops_per_round: float
     collective_bytes: Dict[str, int]
+    op_names: Dict[str, str] = dataclasses.field(default_factory=dict)

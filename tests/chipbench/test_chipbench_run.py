@@ -71,3 +71,37 @@ def test_last_line_has_the_contract_keys():
     assert all(set(m) == {"value", "unit"} for m in line["metrics"].values())
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(line["device"])
     assert all(set(v) == {"value", "limit"} for v in line["checks"].values())
+
+
+def test_traced_run_reads_the_programs_scopes_and_spans(monkeypatch):
+    """A traced run hands the per-layer readers the compiled step's op
+    names and a trace with the program's ``input.batch`` spans.  The CPU
+    profile has no device plane, so each dispatch is given one device
+    operation per instruction of the step, in its host span's place."""
+    from chipbench import scopes, trace
+    from repro.obs import spans
+
+    names = {}
+    parse = scopes.op_names
+    monkeypatch.setattr(scopes, "op_names", lambda text: names.update(parse(text)) or names)
+
+    def load(directory, device_ids=None):
+        spans = trace.host_events(trace.profile(directory))
+        sent = [s for s, _, n in spans if n == "dispatch"]
+        ops = [(s + k, s + k + 1, n) for s in sent for k, n in enumerate(sorted(names))]
+        return trace.Trace(ops={0: ops}, spans=spans,
+                           window=next((s, e) for s, e, n in spans if n == "window"))
+
+    monkeypatch.setattr(trace, "load", load)
+    c = cell(DENSE, traffic(), "internlm2-local")
+    result = harness.run_cell(c, 2 ** 31 + 11, 0.5, True, jax.devices()[:1],
+                              time.time(), peaks.PEAKS["TPU v5 lite"], log=lambda m: None)
+    assert result["correct"] is True
+    metrics = result["metrics"]
+    gossip = {"gossip_exposed_ms", "gossip_bytes", "gossip_ms"}
+    assert set(metrics) == {m["name"] for m in c.per_layer} - gossip
+    assert {"forward_ms", "backward_ms", "optimizer_ms", "host_batch_ms"} <= set(metrics)
+    assert 0 < metrics["host_batch_ms"]["value"] < metrics["host_input_ms"]["value"]
+    assert result["device"]["busy_s"] > 0 and result["device"]["window_s"] > 0
+    assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    assert not spans.enabled()

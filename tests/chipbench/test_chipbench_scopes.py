@@ -1,7 +1,7 @@
 """The program's scopes and spans as the benchmark reads them: op ->
-scope from compiled HLO, device time per round by scope, the new
-per-layer readers, ``repro.obs`` spans in a profiler trace, and the
-dispatch lead on the recorded TPU v5e trace."""
+scope from compiled HLO, device time per round by scope, the per-layer
+readers of the scopes (``named_ms`` among them), ``repro.obs`` spans in
+a profiler trace, and the dispatch lead on the recorded TPU v5e trace."""
 
 import importlib
 import os
@@ -67,8 +67,13 @@ def test_scope_of_reads_the_first_entry_by_name_stack_component():
     assert scopes.scope_of("") == "other"
 
 
+def of_hlo(text):
+    """``{instruction name: scope}`` of an HLO module's text."""
+    return {name: scopes.scope_of(op) for name, op in scopes.op_names(text).items()}
+
+
 def test_of_hlo_on_hand_made_text():
-    got = scopes.of_hlo(HLO)
+    got = of_hlo(HLO)
     # a fusion takes its matmul's scope: a weight gradient fused with
     # its update is backward
     assert got["multiply_subtract_fusion"] == "backward"
@@ -91,7 +96,7 @@ def test_of_hlo_on_the_compiled_tiny_step():
     c = cell(DENSE, traffic(), "internlm2-local")
     sut = harness.SystemUnderTest(c, 2 ** 31 + 5, jax.devices()[:1])
     text = sut.step.lower(sut.state, harness.device_batch(sut.host_batch(0))).compile().as_text()
-    got = scopes.of_hlo(text)
+    got = of_hlo(text)
     assert set(got) == _instruction_names(text)
     assert set(got.values()) <= set(scopes.SCOPES)
     assert {"forward", "backward", "optimizer"} <= set(got.values())
@@ -107,7 +112,7 @@ from chipbench import harness, scopes
 sut = harness.SystemUnderTest(cell(DENSE, traffic(silos=4), "internlm2-ring4"), 2 ** 31 + 7,
                               jax.devices()[:4])
 text = sut.step.lower(sut.state, harness.device_batch(sut.host_batch(0))).compile().as_text()
-got = scopes.of_hlo(text)
+got = {{n: scopes.scope_of(op) for n, op in scopes.op_names(text).items()}}
 print(" ".join(sorted(set(got.values()))))
 print(" ".join(sorted({{got[n] for n in got if "permute" in n}})))
 """
@@ -126,14 +131,18 @@ def test_of_hlo_finds_the_gossip_of_a_four_silo_ring():
     assert permutes == "gossip"
 
 
-def _facts(tr, op_scopes, rounds=2):
-    return scopes.ScopedFacts(trace=tr, rounds=rounds, chips=len(tr.ops),
-                              peak=peaks.peak_for("TPU v5 lite"), flops_per_round=1.0,
-                              collective_bytes={"collective-count": 0}, op_scopes=op_scopes)
+def _facts(tr, op_names, rounds=2):
+    return trace.Facts(trace=tr, rounds=rounds, chips=len(tr.ops),
+                       peak=peaks.peak_for("TPU v5 lite"), flops_per_round=1.0,
+                       collective_bytes={"collective-count": 0}, op_names=op_names)
 
 
 def _read(name, facts):
     return importlib.import_module(f"chipbench.metrics.{name}").read(facts)
+
+
+FORWARD = "jit(step_fn)/while/body/closed_call/jvp(forward)/dot_general"
+BACKWARD = "jit(step_fn)/while/body/closed_call/transpose(jvp(forward))/dot_general"
 
 
 def test_new_readers_on_a_hand_built_trace():
@@ -143,19 +152,43 @@ def test_new_readers_on_a_hand_built_trace():
            1: [(0, 100, "fusion.1")]}
     spans = [(0, 100, "window"), (5, 9, "input.batch"), (50, 56, "input.batch")]
     tr = Trace(ops=ops, spans=spans, window=(0, 100))
-    op_scopes = {"fusion.1": "forward", "convolution.2": "backward",
-                 "collective-permute-done.1": "gossip", "add.3": "optimizer",
-                 "all-reduce": "other"}
-    facts = _facts(tr, op_scopes)
+    op_names = {"fusion.1": FORWARD, "convolution.2": BACKWARD,
+                "collective-permute-done.1": "jit(step_fn)/gossip/shard_map/ppermute",
+                "add.3": "jit(step_fn)/optimizer/add", "all-reduce": "jit(step_fn)/reduce_sum"}
+    facts = _facts(tr, op_names)
     # self time per chip, mean over the chips, per round: ns * 1e-6 = ms
     assert _read("forward_ms", facts) == pytest.approx((30 + 100) / 2 / 2 * 1e-6)
     assert _read("backward_ms", facts) == pytest.approx(10 / 2 / 2 * 1e-6)
     assert _read("gossip_ms", facts) == pytest.approx(20 / 2 / 2 * 1e-6)
     assert _read("optimizer_ms", facts) == pytest.approx(10 / 2 / 2 * 1e-6)
     assert _read("host_batch_ms", facts) == pytest.approx(5 * 1e-6)
-    by_scope = scopes.device_ms(tr, op_scopes, 2)
+    by_scope = scopes.device_ms(tr, op_names, 2)
     assert by_scope["other"] == pytest.approx(20 / 2 / 2 * 1e-6)
     assert sum(by_scope.values()) == pytest.approx(tr.busy_s() / 2 * 1e3)
+
+
+def test_named_ms_reads_a_scope_in_forward_and_backward_alike():
+    """A scope nested under ``forward`` is found in the forward pass, in
+    the backward pass and in the backward's recompute; a scope of the
+    same name as a part of another component is not it."""
+    ops = {0: [(0, 10, "fusion.1"), (10, 40, "fusion.2"), (40, 45, "fusion.3"),
+               (45, 60, "fusion.4"), (60, 100, "fusion.5")],
+           1: [(0, 20, "fusion.1"), (20, 100, "fusion.5")]}
+    tr = Trace(ops=ops, spans=[(0, 100, "window")], window=(0, 100))
+    op_names = {
+        "fusion.1": "jit(step_fn)/jvp(forward)/layer/moe_dispatch/scatter-add",
+        "fusion.2": "jit(step_fn)/transpose(jvp(forward))/layer/moe_dispatch/gather",
+        "fusion.3": "jit(step_fn)/transpose(jvp(forward))/jvp(forward)/checkpoint/"
+                    "rematted_computation/transpose(jvp(moe_dispatch))/mul;jit(step_fn)/x",
+        "fusion.4": "jit(step_fn)/jvp(forward)/moe_dispatch_weights/dot_general",
+        "fusion.5": "jit(step_fn)/optimizer/sub;jit(step_fn)/moe_dispatch/add",
+    }
+    facts = _facts(tr, op_names, rounds=5)
+    want = ((10 + 30 + 5) + 20) / 2 / 5 * 1e-6
+    assert scopes.named_ms(facts, "moe_dispatch") == pytest.approx(want)
+    assert scopes.named_ms(facts, "forward") == pytest.approx((10 + 30 + 5 + 15 + 20) / 2 / 5 * 1e-6)
+    assert scopes.named_ms(facts, "moe_combine") is None
+    assert scopes.named_ms(_facts(tr, {}), "moe_dispatch") is None
 
 
 def test_new_readers_read_nothing_where_the_program_left_nothing():
@@ -164,7 +197,7 @@ def test_new_readers_read_nothing_where_the_program_left_nothing():
                         flops_per_round=1.0, collective_bytes={"collective-count": 0})
     for name in NEW_METRICS:
         assert _read(name, plain) is None
-    local = _facts(tr, {"fusion.1": "forward"}, rounds=1)
+    local = _facts(tr, {"fusion.1": FORWARD}, rounds=1)
     assert _read("forward_ms", local) == pytest.approx(5e-6)
     assert _read("gossip_ms", local) is None
     assert _read("backward_ms", local) is None
@@ -183,7 +216,8 @@ def test_obs_span_lands_in_the_profiler_trace():
             with jax.profiler.trace(tdir):
                 with spans.span("window"):
                     batcher.batch(3)
-            found = scopes.program_spans(tdir)
+            found = [e for e in trace.host_events(trace.profile(tdir))
+                     if e[2] in scopes.PROGRAM_SPANS]
     finally:
         spans.disable()
     records = spans.pop_finished()
@@ -198,8 +232,7 @@ def test_obs_span_lands_in_the_profiler_trace():
 
 
 def test_recorded_trace_dispatch_lead_and_scopes():
-    tr = scopes.load(FIXTURE)
-    assert tr.ops == trace.load(FIXTURE).ops
+    tr = trace.load(FIXTURE)
     assert sorted(tr.modules) == [0, 1, 2, 3]
     assert all(len(m) == 3 for m in tr.modules.values())
     assert len(tr.host_spans("dispatch")) == 3
@@ -220,8 +253,8 @@ def test_dispatch_lead_leaves_out_a_device_it_cannot_pair():
     spans = [(100, 1000, "window"), (110, 120, "dispatch"), (300, 310, "dispatch"),
              (500, 505, "dispatch")]
     early = [(10, 60), (60, 95)]
-    tr = scopes.ProgramTrace(ops={0: [(130, 900, "fusion.1")]}, spans=spans, window=(100, 1000),
-                             modules={0: early + [(130, 400), (400, 700), (700, 900)],
-                                      1: [(125, 400), (400, 700), (700, 900)]})
+    tr = Trace(ops={0: [(130, 900, "fusion.1")]}, spans=spans, window=(100, 1000),
+               modules={0: early + [(130, 400), (400, 700), (700, 900)],
+                        1: [(125, 400), (400, 700), (700, 900)]})
     assert tr.dispatch_lead_ms() == pytest.approx(15e-6)    # device 1, first round
-    assert scopes.ProgramTrace(ops={}, spans=spans, window=(100, 1000)).dispatch_lead_ms() is None
+    assert Trace(ops={}, spans=spans, window=(100, 1000)).dispatch_lead_ms() is None
